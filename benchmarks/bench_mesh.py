@@ -13,37 +13,22 @@ tp>1 pays GSPMD's all-reduces without any extra FLOP throughput and is
 surface and the token-parity invariant, not a speedup: on a real
 multi-chip backend the same config is where the TP win would appear.
 
-Forcing host devices only works BEFORE jax initializes, and
-``benchmarks.run`` imports jax long before this module; ``run()``
-therefore re-executes itself as a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` when the current
-process cannot see enough devices.
+It runs in this process over the devices JAX already sees and fails
+with a clear message when there are too few: a child process could not
+open a chip its parent holds.  On CPU, force host devices in the
+environment before Python starts
+(``XLA_FLAGS=--xla_force_host_platform_device_count=8``, as CI does).
 
 Emits the standard ``name,us_per_call,derived`` CSV rows plus
 ``mesh.json`` in `out_dir`; ``--quick`` shrinks the sweep (CI).
 """
 from __future__ import annotations
 
+import json
 import os
-import sys
+import time
 
-N_DEVICES = 8
-_FLAG = f"--xla_force_host_platform_device_count={N_DEVICES}"
-_FLAG_KEY = "--xla_force_host_platform_device_count"
-
-if (
-    __name__ == "__main__"
-    and "jax" not in sys.modules
-    and _FLAG_KEY not in os.environ.get("XLA_FLAGS", "")
-):
-    # direct invocation: grab the devices while we still can
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") + " " + _FLAG).strip()
-
-import json  # noqa: E402
-import subprocess  # noqa: E402
-import time  # noqa: E402
-
-import numpy as np  # noqa: E402
+import numpy as np
 
 POOL_PAGES = 512
 DECODE_STEPS = 4
@@ -104,7 +89,11 @@ def _measure(out_dir: str, quick: bool) -> None:
     tps = [1, 2] if quick else [1, 2, 4]
     n_req = 8 if quick else 16
     measured = 1 if quick else 2
-    assert len(jax.devices()) >= max(tps), "run() spawns with XLA_FLAGS set"
+    if len(jax.devices()) < max(tps):
+        raise RuntimeError(
+            f"bench_mesh needs {max(tps)} devices for tp={max(tps)}, JAX sees "
+            f"{len(jax.devices())} ({jax.devices()[0].platform}); on CPU set "
+            "XLA_FLAGS=--xla_force_host_platform_device_count=8 before starting")
 
     system, pool_rv, prof, _ = make_tiny_system(
         n_items=60,
@@ -178,25 +167,8 @@ def _measure(out_dir: str, quick: bool) -> None:
 
 
 def run(out_dir: str = "results/bench", quick: bool = False) -> None:
-    """Entry point for ``benchmarks.run``.  jax is already initialized
-    (single host device) by the time this runs, so the sweep executes in
-    a child process that forces the device count first."""
-    need = 2 if quick else 4
-    if "jax" in sys.modules:
-        import jax
-
-        if len(jax.devices()) >= need:
-            _measure(out_dir, quick)
-            return
-    env = dict(os.environ)
-    if _FLAG_KEY not in env.get("XLA_FLAGS", ""):
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + _FLAG).strip()
-    cmd = [sys.executable, "-m", "benchmarks.bench_mesh", "--out", out_dir]
-    if quick:
-        cmd.append("--quick")
-    res = subprocess.run(cmd, env=env)
-    if res.returncode:
-        raise RuntimeError(f"bench_mesh subprocess failed ({res.returncode})")
+    """Entry point for ``benchmarks.run``: the sweep, in this process."""
+    _measure(out_dir, quick)
 
 
 def main(argv=None) -> int:

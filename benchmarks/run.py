@@ -29,6 +29,8 @@ import argparse
 import functools
 import time
 
+from repro.launch.compile_cache import use_compile_cache
+
 print = functools.partial(print, flush=True)   # keep CSV ordered through pipes
 
 
@@ -44,6 +46,7 @@ def main(argv=None) -> int:
                     help="tableIII: train the planted-preference ranker")
     ap.add_argument("--out", default="results/bench")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     print("name,us_per_call,derived")
     t0 = time.time()

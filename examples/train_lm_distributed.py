@@ -37,7 +37,8 @@ def main():
     args = ap.parse_args()
 
     if args.lower_only:
-        from repro.launch.dryrun import run_cell
+        from repro.launch.dryrun import force_host_devices, run_cell
+        force_host_devices()
         run_cell(args.arch, "train_4k", multi_pod=False,
                  out_dir="results/dryrun", skip_existing=False)
         return

@@ -31,12 +31,12 @@ from repro.kernels.selective_attention.ops import (build_block_liveness,
                                                   selective_mha)
 from repro.models import layers as L
 
-# Pallas tile sizes for the serving-path kernels.  The engine's shape
-# buckets are multiples of 64, so these tiles add no padding on the tiny
-# CI models while still being MXU-shaped (padded to 128 lanes by Mosaic)
-# on real hardware.
-PALLAS_Q_BLOCK = 64
-PALLAS_KV_BLOCK = 64
+# Pallas tile sizes for the serving-path kernels: MXU-shaped, and the
+# smallest the TPU accepts for the key-mask blocks, whose key axis is
+# the lane axis (a multiple of 128).  The kernels pad the engine's
+# 64-multiple shape buckets up to these tiles.
+PALLAS_Q_BLOCK = 128
+PALLAS_KV_BLOCK = 128
 
 
 def decode_uses_paged(cfg: LMConfig) -> bool:
@@ -343,6 +343,24 @@ def _pad_to(x: np.ndarray, n: int, fill=0):
                                       x.dtype)])
 
 
+# Eq. 3 divergence at most this share of the cached row's L1 norm is
+# rounding, not a different key.  A reused token's layer-0 K/V is
+# context-free, so it matches its cache exactly on one device; a sharded
+# program sums the same row in another order and lands a few ulps off.
+# `select_recompute` scales divergence by its max, which would turn those
+# ulps into a full-scale score and let them pick the recompute set.
+DIV_RTOL = 1e-2
+
+
+def _divergence(k_raw, ck0, v, cv0):
+    """Per-token Eq. 3 divergence |k - k̂|₁ + |v - v̂|₁ over (T, Hkv, Dh)
+    rows, zero where it is within `DIV_RTOL` of rounding."""
+    div = (jnp.abs(k_raw - ck0).sum(axis=(1, 2))
+           + jnp.abs(v - cv0).sum(axis=(1, 2)))
+    scale = jnp.abs(ck0).sum(axis=(1, 2)) + jnp.abs(cv0).sum(axis=(1, 2))
+    return jnp.where(div <= DIV_RTOL * scale, 0.0, div)
+
+
 def _layer0_impl(params, toks, valid, ck0, cv0, cfg: LMConfig):
     n = toks.shape[0]
     pos = jnp.arange(n)
@@ -356,11 +374,9 @@ def _layer0_impl(params, toks, valid, ck0, cv0, cfg: LMConfig):
                          k_valid=valid)
     # A_i: attention mass received by key i from *valid* queries
     attn_mass = (probs * valid[None, None, :, None]).mean(axis=(0, 1)).sum(axis=0)
-    dk = jnp.abs(k_raw - ck0).sum(axis=(1, 2))
-    dv = jnp.abs(v - cv0).sum(axis=(1, 2))
     x = x + jnp.einsum("she,hed->sd", o, lp["wo"])
     x = x + mlp_block(L.rms_norm(x, lp["mlp_norm"], cfg.norm_eps), lp, cfg)
-    return x, attn_mass, dk + dv, k_raw, v
+    return x, attn_mass, _divergence(k_raw, ck0, v, cv0), k_raw, v
 
 
 @functools.partial(jax.jit, static_argnums=(5,))
@@ -835,11 +851,9 @@ def _jit_layer0_chunk(params, toks_c, offset, valid, ck0_c, cv0_c,
                          return_probs=True, k_valid=valid)
     qvalid = jax.lax.dynamic_slice(valid, (offset,), (C,))
     m_c = (probs * qvalid[None, None, :, None]).mean(axis=(0, 1))
-    dk = jnp.abs(k_raw - ck0_c).sum(axis=(1, 2))
-    dv = jnp.abs(v - cv0_c).sum(axis=(1, 2))
     x = x + jnp.einsum("she,hed->sd", o, lp["wo"])
     x = x + mlp_block(L.rms_norm(x, lp["mlp_norm"], cfg.norm_eps), lp, cfg)
-    return x, m_c, dk + dv, k_raw, v, kbuf, vbuf
+    return x, m_c, _divergence(k_raw, ck0_c, v, cv0_c), k_raw, v, kbuf, vbuf
 
 
 @jax.jit
@@ -890,8 +904,9 @@ class ChunkedPrefill:
         self.ckp = _pad_to(cached_k.astype(np.float32), self.n_pad)
         self.cvp = _pad_to(cached_v.astype(np.float32), self.n_pad)
         Hkv, Dh = cfg.n_kv_heads, cfg.resolved_head_dim
-        self.kbuf = jnp.zeros((self.n_pad, Hkv, Dh), jnp.float32)
-        self.vbuf = jnp.zeros((self.n_pad, Hkv, Dh), jnp.float32)
+        # the model's dtype, like the monolithic layer 0's keys
+        self.kbuf = jnp.zeros((self.n_pad, Hkv, Dh), jnp.dtype(cfg.dtype))
+        self.vbuf = jnp.zeros((self.n_pad, Hkv, Dh), jnp.dtype(cfg.dtype))
         self.offset = 0
         self._xs: list = []
         self._ms: list = []

@@ -134,21 +134,46 @@ class RcLLMSystem:
         return logits[SY.SLOT_BASE:SY.SLOT_BASE + n_cand], stats
 
 
+CATALOG_VOCAB = 4096   # token ids of the synthetic catalog and reviews
+
+
+def tiny_lm_config(n_layers: int = 4, d_model: int = 64, n_heads: int = 4,
+                   n_kv_heads: int = 2) -> LMConfig:
+    """The small float32 model the CPU tests and benchmarks serve."""
+    return LMConfig(name="rcllm-tiny", n_layers=n_layers, d_model=d_model,
+                    n_heads=n_heads, n_kv_heads=n_kv_heads, head_dim=16,
+                    d_ff=128, vocab_size=CATALOG_VOCAB, mlp_type="swiglu",
+                    dtype="float32", attn_q_chunk=64, attn_kv_chunk=64,
+                    remat=False)
+
+
 def make_tiny_system(profile_name: str = "amazon", n_items: int = 300,
                      k_instances: int = 4, n_requests_hist: int = 200,
                      seed: int = 0, n_layers: int = 4, d_model: int = 64,
                      item_coverage: float = 1.0, n_heads: int = 4,
-                     n_kv_heads: int = 2):
-    """A small end-to-end RcLLM instance for tests/benchmarks on CPU.
-    ``n_heads``/``n_kv_heads`` are overridable so the mesh parity tests
-    can build a model whose head counts divide higher tp degrees."""
+                     n_kv_heads: int = 2, cfg: Optional[LMConfig] = None):
+    """A small synthetic catalog and trace history, served by the model
+    `cfg` with random weights from `seed`.
+
+    Without `cfg` the model is `tiny_lm_config` at the given widths
+    (``n_heads``/``n_kv_heads`` are overridable so the mesh parity tests
+    can build a model whose head counts divide higher tp degrees).  Any
+    `LMConfig` whose vocabulary covers the catalog's token ids works —
+    the chip smoke serves Qwen3-8B widths through this builder."""
     from repro.models import transformer as T
 
+    if cfg is None:
+        cfg = tiny_lm_config(n_layers, d_model, n_heads, n_kv_heads)
+    if cfg.vocab_size < CATALOG_VOCAB:
+        raise ValueError(
+            f"vocab_size={cfg.vocab_size} cannot hold the synthetic "
+            f"catalog's token ids (< {CATALOG_VOCAB})")
     prof = dataclasses.replace(SY.PROFILES[profile_name], n_items=n_items,
                                n_clusters=max(6, n_items // 50),
                                mean_item_tokens=24, mean_review_tokens=20)
-    catalog = SY.make_catalog(prof, vocab_size=4096, seed=seed)
-    pool = SY.make_review_pool(vocab_size=4096, n_phrases=120, seed=seed + 1)
+    catalog = SY.make_catalog(prof, vocab_size=CATALOG_VOCAB, seed=seed)
+    pool = SY.make_review_pool(vocab_size=CATALOG_VOCAB, n_phrases=120,
+                               seed=seed + 1)
     hist = SY.make_trace(catalog, pool, prof, n_requests=n_requests_hist,
                          qps=10.0, n_users=40, n_candidates=8,
                          reviews_per_user=2, seed=seed + 2)
@@ -159,10 +184,6 @@ def make_tiny_system(profile_name: str = "amazon", n_items: int = 300,
             corpus.append(r.history_tokens)
             seen.add(r.user_id)
 
-    cfg = LMConfig(name="rcllm-tiny", n_layers=n_layers, d_model=d_model,
-                   n_heads=n_heads, n_kv_heads=n_kv_heads, head_dim=16, d_ff=128,
-                   vocab_size=4096, mlp_type="swiglu", dtype="float32",
-                   attn_q_chunk=64, attn_kv_chunk=64, remat=False)
     params = T.init_params(jax.random.PRNGKey(seed), cfg)
     system = RcLLMSystem.build(params, cfg, catalog, corpus, hist,
                                k_instances=k_instances,

@@ -11,10 +11,12 @@ Two mask sources compose:
 * ``causal`` — the static iota-based triangle (contiguous positions);
 * ``kv_valid`` — an optional per-row key-liveness bitmap, the serving
   engine's ragged-batch mask (padded prompt tails, paged-decode slots
-  past a request's length).  It rides in as a normal kernel input tiled
-  (1, kv_block) with NB mask rows shared across each row's heads by
-  BlockSpec index arithmetic — never materialized per head — so the
-  wrapper stays jit-traceable end-to-end.
+  past a request's length).  It rides in as a normal int32 kernel input
+  laid out (NB, 1, Skv) and tiled (1, 1, kv_block) — the singleton
+  sublane axis keeps the block's trailing dims tile-legal for Mosaic
+  (kv_block a multiple of 128 on TPU) — with NB mask rows shared across
+  each row's heads by BlockSpec index arithmetic, never materialized per
+  head, so the wrapper stays jit-traceable end-to-end.
 """
 from __future__ import annotations
 
@@ -56,7 +58,7 @@ def _flash_kernel(*refs, causal: bool, sm_scale: float, q_block: int,
                                                      (q_block, kv_block), 1)
     mask = k_pos < kv_len
     if has_valid:
-        mask &= valid_ref[0][None, :] > 0
+        mask &= valid_ref[0] > 0                    # (1, kv_block) row
     if causal:
         mask &= q_pos >= k_pos
     s = jnp.where(mask, s, NEG_INF)
@@ -86,10 +88,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     kv_block: int = 128,
                     interpret: bool = False) -> jax.Array:
     """q: (BH, Sq, D); k, v: (BH, Skv, D) — heads pre-flattened (GQA groups
-    expanded by the ops wrapper).  `kv_valid`: optional (NB, Skv) bool/int8
+    expanded by the ops wrapper).  `kv_valid`: optional (NB, Skv) bool/int
     key-liveness mask with NB dividing BH — mask row b·NB/BH serves
     flattened row b, so a per-request mask is shared by that request's
-    heads without per-head copies.  Returns (BH, Sq, D)."""
+    heads without per-head copies.  On TPU, q_block must be a multiple of
+    8 and kv_block of 128 (Mosaic's tile).  Returns (BH, Sq, D)."""
     bh, sq, d = q.shape
     skv = k.shape[1]
     sq_p = ((sq + q_block - 1) // q_block) * q_block
@@ -112,10 +115,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         nb = kv_valid.shape[0]
         if bh % nb:
             raise ValueError(f"kv_valid batch {nb} must divide BH={bh}")
-        kvv = jnp.pad(kv_valid.astype(jnp.int8),
-                      ((0, 0), (0, skv_p - skv)))
-        in_specs.append(pl.BlockSpec((1, kv_block),
-                                     lambda b, qi, ki: (b * nb // bh, ki)))
+        kvv = jnp.pad(kv_valid.astype(jnp.int32),
+                      ((0, 0), (0, skv_p - skv)))[:, None, :]
+        in_specs.append(pl.BlockSpec((1, 1, kv_block),
+                                     lambda b, qi, ki: (b * nb // bh, 0, ki)))
         args.append(kvv)
 
     kernel = functools.partial(

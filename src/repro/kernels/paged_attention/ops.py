@@ -6,7 +6,7 @@ tile (padded to `q_block` so tiny group factors still fill the MXU's
 sublane dimension), and dispatches one kernel launch for one layer.
 The layer index is static: the decode step's Python layer loop issues
 one call per layer, and each call's BlockSpec index maps touch only
-that layer's (page, Hkv, Dh) planes of the referenced pages.
+that layer's (page, Dh) planes of the referenced pages.
 """
 from __future__ import annotations
 
@@ -34,12 +34,12 @@ def paged_decode_mha(
     interpret: bool = False,
 ) -> jax.Array:
     """q: (N, Hq, Dh) post-RoPE decode queries; arena_k/arena_v:
-    (P, page, L, Hkv, Dh) paged pool; page_ids: (N, Pmax); slot_pos:
+    (P, L, Hkv, page, Dh) paged pool; page_ids: (N, Pmax); slot_pos:
     (N, Pmax, page) logical position per slot or -1 (see
     `kv_pool.page_views`).  -> (N, Hq, Dh).
     """
     n, hq, d = q.shape
-    hkv = arena_k.shape[3]
+    hkv = arena_k.shape[2]
     g = hq // hkv
     if g * hkv != hq:
         raise ValueError(f"n_heads {hq} not divisible by n_kv_heads {hkv}")

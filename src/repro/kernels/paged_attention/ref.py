@@ -67,18 +67,18 @@ def paged_decode_ref(
     """Materializing oracle for `paged_attention.kernel`.
 
     q: (N, Hq, Dh) post-RoPE single-token queries;
-    arena_k/arena_v: (P, page, L, Hkv, Dh) paged pool (keys pre-RoPE);
+    arena_k/arena_v: (P, L, Hkv, page, Dh) paged pool (keys pre-RoPE);
     page_ids: (N, Pmax) physical page per referenced page-view column;
     slot_pos: (N, Pmax, page) logical position served by each slot of
     the referenced page, or -1 for slots holding no live token of the
     row.  -> (N, Hq, Dh).
     """
     n, pmax = page_ids.shape
-    page = arena_k.shape[1]
-    hkv, d = arena_k.shape[3], arena_k.shape[4]
+    hkv, page, d = arena_k.shape[2:]
     flat = page_ids.reshape(-1)
-    kg = jnp.take(arena_k[:, :, layer], flat, axis=0)
-    vg = jnp.take(arena_v[:, :, layer], flat, axis=0)
+    # (N*Pmax, Hkv, page, Dh) -> (N, Pmax*page, Hkv, Dh)
+    kg = jnp.take(arena_k[:, layer], flat, axis=0).transpose(0, 2, 1, 3)
+    vg = jnp.take(arena_v[:, layer], flat, axis=0).transpose(0, 2, 1, 3)
     kg = kg.reshape(n, pmax * page, hkv, d)
     vg = vg.reshape(n, pmax * page, hkv, d)
     pos = slot_pos.reshape(n, pmax * page)

@@ -81,11 +81,14 @@ def block_liveness(q_positions, hh_mask, *, window: int,
     return live
 
 
-def _sel_kernel(qpos_ref, live_ref, q_ref, k_ref, v_ref, mask_ref,
+def _sel_kernel(live_ref, qpos_ref, q_ref, k_ref, v_ref, mask_ref,
                 o_ref, m_scr, l_scr, acc_scr,
                 *, sm_scale: float, q_block: int, kv_block: int,
-                window: int, kv_len: int):
+                window: int, kv_len: int, nb: int, bh: int):
+    b = pl.program_id(0)
+    qi = pl.program_id(1)
     ki = pl.program_id(2)
+    nq = pl.num_programs(1)
     nk = pl.num_programs(2)
 
     @pl.when(ki == 0)
@@ -94,18 +97,20 @@ def _sel_kernel(qpos_ref, live_ref, q_ref, k_ref, v_ref, mask_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(live_ref[0, 0, 0] > 0)
+    # the liveness map is scalar-prefetched into SMEM, flattened
+    # (NB, nq, nk) -> one int per tile
+    @pl.when(live_ref[((b * nb // bh) * nq + qi) * nk + ki] > 0)
     def _compute():
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * sm_scale
-        q_pos = qpos_ref[0][:, None]                        # (q_block, 1)
+        q_pos = qpos_ref[0]                                 # (q_block, 1)
         k_pos = ki * kv_block + jax.lax.broadcasted_iota(
             jnp.int32, (q_block, kv_block), 1)
         in_window = (q_pos >= k_pos) & (q_pos - k_pos < window)
-        hh = mask_ref[0][None, :] > 0                       # heavy hitters
+        hh = mask_ref[0] > 0                    # (1, kv_block) heavy hitters
         causal = q_pos >= k_pos
         valid = (k_pos < kv_len) & causal & (in_window | hh)
         s = jnp.where(valid, s, NEG_INF)
@@ -137,7 +142,13 @@ def selective_attention(q: jax.Array, q_positions: jax.Array,
     (mask row b·NB/BH serves flattened row b).  Attend where causal AND
     (within `window` OR hh_mask).  `live`: optional precomputed
     (NB, nq, nk) block-liveness map (`block_liveness`); required for
-    jit-traced calls, computed host-side when omitted."""
+    jit-traced calls, computed host-side when omitted.
+
+    Layouts are chosen so every block is tile-legal for Mosaic: query
+    positions ride as a (NB, R, 1) column (block (1, q_block, 1)), the
+    heavy-hitter bitmap as a (NB, 1, S) row (block (1, 1, kv_block)),
+    and the liveness map is scalar-prefetched.  On TPU q_block must be a
+    multiple of 8 and kv_block of 128."""
     bh, r, d = q.shape
     s_len = k.shape[1]
     qp2 = q_positions if q_positions.ndim == 2 else q_positions[None]
@@ -150,10 +161,10 @@ def selective_attention(q: jax.Array, q_positions: jax.Array,
     s_p = ((s_len + kv_block - 1) // kv_block) * kv_block
     q = jnp.pad(q, ((0, 0), (0, r_p - r), (0, 0)))
     qpos = jnp.pad(qp2.astype(jnp.int32), ((0, 0), (0, r_p - r)),
-                   constant_values=-1)
+                   constant_values=-1)[:, :, None]
     k = jnp.pad(k, ((0, 0), (0, s_p - s_len), (0, 0)))
     v = jnp.pad(v, ((0, 0), (0, s_p - s_len), (0, 0)))
-    hh = jnp.pad(hh2.astype(jnp.int8), ((0, 0), (0, s_p - s_len)))
+    hh = jnp.pad(hh2.astype(jnp.int32), ((0, 0), (0, s_p - s_len)))[:, None]
     nq, nk = r_p // q_block, s_p // kv_block
 
     if live is None:
@@ -165,29 +176,37 @@ def selective_attention(q: jax.Array, q_positions: jax.Array,
     live = jnp.asarray(live, jnp.int32)
     if live.ndim == 2:
         live = live[None]
+    if live.shape != (nb, nq, nk):
+        raise ValueError(
+            f"liveness map {live.shape} != (NB, nq, nk) = {(nb, nq, nk)}")
 
     kernel = functools.partial(
         _sel_kernel, sm_scale=1.0 / d ** 0.5, q_block=q_block,
-        kv_block=kv_block, window=window, kv_len=s_len)
-    out = pl.pallas_call(
-        kernel,
+        kv_block=kv_block, window=window, kv_len=s_len, nb=nb, bh=bh)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(bh, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, q_block), lambda b, qi, ki: (b * nb // bh, qi)),
-            pl.BlockSpec((1, 1, 1),
-                         lambda b, qi, ki: (b * nb // bh, qi, ki)),
-            pl.BlockSpec((1, q_block, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, kv_block, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, kv_block, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, kv_block), lambda b, qi, ki: (b * nb // bh, ki)),
+            pl.BlockSpec((1, q_block, 1),
+                         lambda b, qi, ki, lv: (b * nb // bh, qi, 0)),
+            pl.BlockSpec((1, q_block, d), lambda b, qi, ki, lv: (b, qi, 0)),
+            pl.BlockSpec((1, kv_block, d), lambda b, qi, ki, lv: (b, ki, 0)),
+            pl.BlockSpec((1, kv_block, d), lambda b, qi, ki, lv: (b, ki, 0)),
+            pl.BlockSpec((1, 1, kv_block),
+                         lambda b, qi, ki, lv: (b * nb // bh, 0, ki)),
         ],
-        out_specs=pl.BlockSpec((1, q_block, d), lambda b, qi, ki: (b, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, r_p, d), q.dtype),
+        out_specs=pl.BlockSpec((1, q_block, d),
+                               lambda b, qi, ki, lv: (b, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((q_block,), jnp.float32),
             pltpu.VMEM((q_block,), jnp.float32),
             pltpu.VMEM((q_block, d), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((bh, r_p, d), q.dtype),
         interpret=interpret,
-    )(qpos, live, q, k, v, hh)
+    )(live.reshape(-1), qpos, q, k, v, hh)
     return out[:, :r]

@@ -1,20 +1,24 @@
+import argparse
+import json
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+import time
+import traceback
 
-# The two lines above MUST run before any jax import — jax locks the device
-# count at first init.  Everything else follows.
-import argparse          # noqa: E402
-import json              # noqa: E402
-import time              # noqa: E402
-import traceback         # noqa: E402
+import jax
 
-import jax               # noqa: E402
+from repro.configs import registry as R
+from repro.launch import steps as STEPS
+from repro.launch.mesh import make_production_mesh
+from repro.launch.roofline import collective_bytes_from_hlo, roofline_terms
 
-from repro.configs import registry as R                    # noqa: E402
-from repro.launch import steps as STEPS                    # noqa: E402
-from repro.launch.mesh import make_production_mesh         # noqa: E402
-from repro.launch.roofline import (collective_bytes_from_hlo,  # noqa: E402
-                                   roofline_terms)
+
+def force_host_devices() -> None:
+    """Give XLA:CPU the 512 host devices the production meshes need.
+
+    XLA reads the flag once, at JAX's first use of a backend, so a
+    launcher calls this before it touches any device.  Importing this
+    module sets nothing: the flag stays off every chip path."""
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 
 def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str,
@@ -84,6 +88,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str,
 
 
 def main():
+    force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="all")
     ap.add_argument("--shape", default="all")

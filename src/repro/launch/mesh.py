@@ -16,6 +16,7 @@ from __future__ import annotations
 from typing import Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
 def factor_devices(n: int) -> Tuple[int, int]:
@@ -68,7 +69,11 @@ def make_production_mesh(
             f"dry-run sets XLA_FLAGS=--xla_force_host_platform_device_count"
             f"=512 before importing jax"
         )
-    return jax.make_mesh(shape, axis_names, devices=devices[:n])
+    # Auto axes: GSPMD propagates the param/arena shardings through the
+    # model code, which carries no sharding annotations of its own
+    # (make_mesh's default, Explicit, would demand one per gather)
+    return jax.make_mesh(shape, axis_names, devices=devices[:n],
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def data_axes(mesh) -> tuple:

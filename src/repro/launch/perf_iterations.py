@@ -1,5 +1,9 @@
 import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
+
+if __name__ == "__main__":
+    # 512 host devices for the production meshes, before JAX's first
+    # backend use; importing this module sets nothing
+    os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 
 """§Perf hillclimb driver — three cells, hypothesis → change → measure.
 
@@ -186,8 +190,7 @@ def cell_C(out, probe: bool):
         "terms": t3,
         "confirmed": t3["overlapped_s"] < t2["serial_s"]})
     if probe:
-        mesh64 = jax.make_mesh((64, 4), ("data", "model"),
-                               devices=jax.devices()[:256])
+        mesh64 = make_production_mesh(shape=(64, 4))
         log.append({"iter": "evidence",
                     "name": "compile probe: (64,4) mesh lowers + memory",
                     "probe": compile_probe(arch, shape, mesh=mesh64)})

@@ -71,6 +71,7 @@ import numpy as np
 from repro.configs import registry as REG
 from repro.core import cost_model as CM
 from repro.core import simulator as SIM
+from repro.launch.compile_cache import use_compile_cache
 from repro.serving.api import ServeConfig, SubmitRequest
 
 
@@ -576,6 +577,7 @@ def main(argv=None):
     ap.add_argument("--page-size", type=int, default=None)
     ap.add_argument("--pages", type=int, default=None)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     try:
         config = build_config(args)

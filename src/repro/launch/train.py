@@ -34,7 +34,8 @@ def main():
 
     fam = R.family_of(args.arch) if args.arch in R.ASSIGNED else "lm"
     if not args.smoke:
-        from repro.launch.dryrun import run_cell
+        from repro.launch.dryrun import force_host_devices, run_cell
+        force_host_devices()
         shape = {"lm": "train_4k", "recsys": "train_batch",
                  "gnn": "full_graph_sm"}[fam]
         run_cell(args.arch, shape, multi_pod=False,

@@ -772,11 +772,14 @@ class Completion:
 
 
 # ------------------------------------------------------- sliced builders
-def build_engine(params, lm_cfg, config: ServeConfig, pool=None, sel=None):
+def build_engine(params, lm_cfg, config: ServeConfig, pool=None, sel=None,
+                 device=None):
     """`BatchEngine` from the config's engine/pool/reuse/mesh slice.  The
     returned engine's `cfg` carries the attention backend and decode
     kernel; `pool`/`sel` override only when a caller needs a bespoke
-    pool (tests) or selective budget.
+    pool (tests) or selective budget.  `device` (without a mesh) puts the
+    engine's params, KV arena and block store on that one device — a
+    cluster worker's own chip; None leaves them on the default device.
 
     With ``config.mesh`` enabled this is the one place the mesh becomes
     physical: the param tree is placed by the `sharding.specs`
@@ -791,12 +794,22 @@ def build_engine(params, lm_cfg, config: ServeConfig, pool=None, sel=None):
     cfg = config.apply_to(lm_cfg)
     mesh = config.mesh.build()
     if mesh is not None:
+        if device is not None:
+            raise ValueError("build_engine: pass a mesh or a device, not both")
         from repro.sharding.specs import shard_lm_params
 
         params = shard_lm_params(params, cfg, mesh)
+    elif device is not None:
+        import jax
+
+        params = jax.device_put(params, device)
     if pool is None:
         pool = pool_for(
-            cfg, page_size=config.page_size, n_pages=config.n_pages, mesh=mesh
+            cfg,
+            page_size=config.page_size,
+            n_pages=config.n_pages,
+            mesh=mesh,
+            device=device,
         )
     if sel is None:
         sel = ENG.SelectiveConfig(r_item=config.r_item, r_rev=config.r_rev)

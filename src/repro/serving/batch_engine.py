@@ -39,6 +39,7 @@ decode) so steady-state serving retraces O(1) times.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
 
@@ -220,12 +221,13 @@ def _decode_step(
     logical position len) through the same page view as every cached
     token — the gather path's explicit concat disappears.
 
-    Jitted below with the arenas donated on TPU/GPU so the update is
-    in-place; CPU doesn't implement donation, so there each step copies
-    the arenas (fine at test scale).
+    Jitted by `_decode_step_jit` with the arenas donated where the
+    backend implements donation (TPU/GPU), so the update is in-place;
+    CPU doesn't, so there each step copies the arenas (fine at test
+    scale).
     """
     N = toks.shape[0]
-    page = arena_k.shape[1]
+    page = arena_k.shape[3]
     S = slot_tables.shape[1]
 
     x = params["embed"][toks].astype(jnp.dtype(cfg.dtype))  # (N, D)
@@ -238,8 +240,8 @@ def _decode_step(
         # one arena gather per step: slot-granular, so a row may
         # interleave private pages with store-shared pages
         # -> (N, S, L, Hkv, Dh)
-        kg = arena_k[slot_tables // page, slot_tables % page]
-        vg = arena_v[slot_tables // page, slot_tables % page]
+        kg = arena_k[slot_tables // page, :, :, slot_tables % page]
+        vg = arena_v[slot_tables // page, :, :, slot_tables % page]
         slot_idx = jnp.arange(S)
         kv_pos = jnp.concatenate(
             [jnp.broadcast_to(slot_idx[None], (N, S)), pos_new[:, None]],
@@ -256,10 +258,10 @@ def _decode_step(
         q = jnp.einsum("nd,dhe->nhe", h, lp["wq"])
         k_new = jnp.einsum("nd,dhe->nhe", h, lp["wk"])  # pre-RoPE
         v_new = jnp.einsum("nd,dhe->nhe", h, lp["wv"])
-        arena_k = arena_k.at[new_pages, new_slots, layer].set(
+        arena_k = arena_k.at[new_pages, layer, :, new_slots].set(
             k_new.astype(arena_k.dtype)
         )
-        arena_v = arena_v.at[new_pages, new_slots, layer].set(
+        arena_v = arena_v.at[new_pages, layer, :, new_slots].set(
             v_new.astype(arena_v.dtype)
         )
 
@@ -281,7 +283,7 @@ def _decode_step(
             v_l = jnp.concatenate([vg[:, :, layer], v_new[:, None]], axis=1)
             k_l = L.apply_rope(k_l, kv_pos, cfg.rope_theta)  # realign
             o = _decode_attn(q, k_l, v_l, kv_valid)
-        x = x + jnp.einsum("nhe,hed->nd", o, lp["wo"])
+        x = x + jnp.einsum("nhe,hed->nd", o.astype(x.dtype), lp["wo"])
         x = x + ENG.mlp_block(
             L.rms_norm(x, lp["mlp_norm"], cfg.norm_eps), lp, cfg
         )
@@ -291,12 +293,22 @@ def _decode_step(
     return xf @ head, arena_k, arena_v
 
 
-if jax.default_backend() in ("tpu", "gpu"):
-    _jit_decode_step = jax.jit(
-        _decode_step, static_argnums=(10,), donate_argnums=(8, 9)
+@functools.lru_cache(maxsize=None)
+def _decode_step_jit(donate: bool):
+    """The jitted decode step, built on first use: with ``donate`` the
+    arenas (args 8, 9) are donated so the per-token KV write is
+    in-place.  Deciding here rather than at import keeps importing this
+    module from touching any backend."""
+    return jax.jit(
+        _decode_step,
+        static_argnums=(10,),
+        donate_argnums=(8, 9) if donate else (),
     )
-else:
-    _jit_decode_step = jax.jit(_decode_step, static_argnums=(10,))
+
+
+def _donates(arr) -> bool:
+    """Does the backend holding `arr` implement buffer donation?"""
+    return any(d.platform in ("tpu", "gpu") for d in arr.devices())
 
 
 class BatchEngine:
@@ -361,8 +373,9 @@ class BatchEngine:
         # copy, so the rows are buffered host-side and fused into the
         # finalize scatter instead — nothing reads a request's rows
         # before its decode starts, so the two modes are byte-identical.
+        self.donate = _donates(self.pool.arena_k)
         if eager_kv_writes is None:
-            eager_kv_writes = jax.default_backend() in ("tpu", "gpu")
+            eager_kv_writes = self.donate
         self.eager_kv_writes = eager_kv_writes
         self.store_refs: Dict[int, list] = {}
         self.last_stats: Dict[int, ENG.EngineStats] = {}
@@ -1207,7 +1220,7 @@ class BatchEngine:
             # shape-stable so they never force a retrace
             pg_ids = np.zeros((n_pad, 1), np.int32)
             sl_pos = np.full((n_pad, 1, self.pool.page_size), -1, np.int32)
-        logits, ak, av = _jit_decode_step(
+        logits, ak, av = _decode_step_jit(self.donate)(
             self.params,
             jnp.asarray(toks),
             jnp.asarray(tables_p),

@@ -219,6 +219,7 @@ class ClusterEngine:
         seed: int = 0,
         alpha: float = 0.7,
         beta: float = 0.3,
+        devices: Optional[Sequence] = None,
         **legacy,
     ):
         if legacy:
@@ -272,19 +273,29 @@ class ClusterEngine:
         self.cfg = config.apply_to(system.cfg)
         self.kv_reuse = config.kv_reuse
         self._item_keys: Dict[int, tuple] = {}
-        # under a real mesh each worker gets a home device (round-robin
-        # over the host's devices): cross-shard item pulls become real
-        # jax.device_put device-to-device copies whose *measured* wall
-        # time is billed instead of the modeled network time
-        self.worker_devices = None
-        if config.mesh.enabled:
-            import jax
+        # every worker gets a home device, round-robin over `devices`
+        # (default: this process's local devices).  Without a mesh the
+        # worker's params, KV arena and block store live there — K
+        # workers on K chips are K one-chip replicas behind the router.
+        # Under a real mesh every engine spans the mesh instead, and the
+        # home device only anchors transfers: cross-shard item pulls
+        # become real jax.device_put device-to-device copies whose
+        # *measured* wall time is billed instead of the modeled network
+        # time
+        import jax
 
-            devs = jax.devices()
-            self.worker_devices = [devs[w % len(devs)] for w in range(k)]
+        devs = list(devices) if devices is not None else jax.local_devices()
+        homes = [devs[w % len(devs)] for w in range(k)]
+        self.worker_devices = homes if config.mesh.enabled else None
         self.backends: List[ClusterWorkerBackend] = []
         for w in range(k):
-            engine = API.build_engine(system.params, system.cfg, config, sel=sel)
+            engine = API.build_engine(
+                system.params,
+                system.cfg,
+                config,
+                sel=sel,
+                device=None if config.mesh.enabled else homes[w],
+            )
             shard = None
             if system.item_store is not None:
                 shard = IC.ShardClient(

@@ -133,20 +133,27 @@ class KVExport:
 class PagedKVPool:
     """Fixed-page KV arena + free-list allocator + per-request slot tables.
 
-    Arena layout: (n_pages, page_size, n_layers, n_kv_heads, head_dim)
-    for K and V separately, dtype float32 (pre-RoPE values).
+    Arena layout: (n_pages, n_layers, n_kv_heads, page_size, head_dim)
+    for K and V separately, dtype float32 (pre-RoPE values).  The
+    (page_size, head_dim) plane of one layer's one kv head is trailing,
+    so the paged-decode kernel reads it as one tile Mosaic accepts.
+    Token rows still go in and out as (t, L, Hkv, Dh):
+    ``arena[pages, :, :, slots]`` puts the token axis first.
+
+    ``device`` places the arenas on one device (a cluster worker's
+    chip); ``mesh`` shards them instead.
     """
 
     def __init__(self, n_layers: int, n_kv_heads: int, head_dim: int,
                  page_size: int = 16, n_pages: int = 512,
-                 dtype: str = "float32", mesh=None):
+                 dtype: str = "float32", mesh=None, device=None):
         self.page_size = int(page_size)
         self.n_pages = int(n_pages)
         self.n_layers = n_layers
         self.mesh = mesh
-        shape = (self.n_pages, self.page_size, n_layers, n_kv_heads, head_dim)
-        arena_k = jnp.zeros(shape, jnp.dtype(dtype))
-        arena_v = jnp.zeros(shape, jnp.dtype(dtype))
+        shape = (self.n_pages, n_layers, n_kv_heads, self.page_size, head_dim)
+        arena_k = jnp.zeros(shape, jnp.dtype(dtype), device=device)
+        arena_v = jnp.zeros(shape, jnp.dtype(dtype), device=device)
         if mesh is not None:
             # per-device arena planes: each device holds every page but
             # only its slice of the kv-head axis (the wk/wv head split).
@@ -191,9 +198,9 @@ class PagedKVPool:
     def bytes_per_token(self) -> int:
         """fp32 K+V bytes one token row occupies across all layers —
         the unit spill-tier capacity and transfer modeling price in."""
-        return int(
-            2 * self.arena_k.dtype.itemsize * np.prod(self.arena_k.shape[2:])
-        )
+        _, n_layers, n_kv_heads, _, head_dim = self.arena_k.shape
+        row = n_layers * n_kv_heads * head_dim
+        return int(2 * self.arena_k.dtype.itemsize * row)
 
     @property
     def free_pages(self) -> int:
@@ -403,14 +410,14 @@ class PagedKVPool:
         v = np.concatenate(vs)
         pages, slots, k, v = _pad_scatter(pages, slots, k, v)
         if deep:
-            self.arena_k = self.arena_k.at[pages, slots, 1:].set(k)
-            self.arena_v = self.arena_v.at[pages, slots, 1:].set(v)
+            self.arena_k = self.arena_k.at[pages, 1:, :, slots].set(k)
+            self.arena_v = self.arena_v.at[pages, 1:, :, slots].set(v)
         elif layer is None:
-            self.arena_k = self.arena_k.at[pages, slots].set(k)
-            self.arena_v = self.arena_v.at[pages, slots].set(v)
+            self.arena_k = self.arena_k.at[pages, :, :, slots].set(k)
+            self.arena_v = self.arena_v.at[pages, :, :, slots].set(v)
         else:
-            self.arena_k = self.arena_k.at[pages, slots, layer].set(k)
-            self.arena_v = self.arena_v.at[pages, slots, layer].set(v)
+            self.arena_k = self.arena_k.at[pages, layer, :, slots].set(k)
+            self.arena_v = self.arena_v.at[pages, layer, :, slots].set(v)
 
     def write_slots(self, slot_ids: np.ndarray,
                     k: np.ndarray, v: np.ndarray) -> None:
@@ -433,8 +440,8 @@ class PagedKVPool:
         pages = slot_ids // self.page_size
         slots = slot_ids % self.page_size
         pages, slots, k, v = _pad_scatter(pages, slots, k, v)
-        self.arena_k = self.arena_k.at[pages, slots].set(k)
-        self.arena_v = self.arena_v.at[pages, slots].set(v)
+        self.arena_k = self.arena_k.at[pages, :, :, slots].set(k)
+        self.arena_v = self.arena_v.at[pages, :, :, slots].set(v)
 
     def write_prompt(self, rid: int, k: np.ndarray, v: np.ndarray) -> None:
         """Insert a full prompt cache (n, L, Hkv, Dh) starting at slot 0."""
@@ -527,12 +534,11 @@ class PagedKVPool:
         spare_off = (spare % self.page_size if len(spare)
                      else np.zeros(0, np.int64))
         page_idx = np.asarray(pages, np.int64)
-        page_k = np.asarray(self.arena_k[page_idx], np.float32) \
-            if len(pages) else np.zeros(
-                (0,) + self.arena_k.shape[1:], np.float32)
-        page_v = np.asarray(self.arena_v[page_idx], np.float32) \
-            if len(pages) else np.zeros(
-                (0,) + self.arena_v.shape[1:], np.float32)
+        page_k = np.asarray(self.arena_k[page_idx], np.float32)
+        page_v = np.asarray(self.arena_v[page_idx], np.float32)
+        # host layout (P, page_size, L, Hkv, Dh): slot rows, page-major
+        page_k = page_k.transpose(0, 3, 1, 2, 4)
+        page_v = page_v.transpose(0, 3, 1, 2, 4)
         return KVExport(rid=rid, seq_len=self.seq_lens[rid],
                         page_size=self.page_size, owner_page=owner_page,
                         owner_off=owner_off, foreign_slots=foreign_slots,
@@ -603,8 +609,8 @@ class PagedKVPool:
         n = self.seq_lens[rid]
         sl = self.slot_tables[rid][:n]
         pages, slots = sl // self.page_size, sl % self.page_size
-        k = np.asarray(self.arena_k[pages, slots])
-        v = np.asarray(self.arena_v[pages, slots])
+        k = np.asarray(self.arena_k[pages, :, :, slots])
+        v = np.asarray(self.arena_v[pages, :, :, slots])
         return k, v
 
     def batch_tables(self, rids: Sequence[int], pad_pages_to: int = 4
@@ -695,8 +701,10 @@ def page_views(tables: np.ndarray, lens: np.ndarray,
 
 
 def pool_for(cfg: LMConfig, page_size: int = 16, n_pages: int = 512,
-             mesh=None) -> PagedKVPool:
+             mesh=None, device=None) -> PagedKVPool:
     """Pool sized from a model config (serving launcher convenience).
-    With `mesh`, the arenas are sharded over its model axis."""
+    With `mesh`, the arenas are sharded over its model axis; with
+    `device`, they live on that one device."""
     return PagedKVPool(cfg.n_layers, cfg.n_kv_heads, cfg.resolved_head_dim,
-                       page_size=page_size, n_pages=n_pages, mesh=mesh)
+                       page_size=page_size, n_pages=n_pages, mesh=mesh,
+                       device=device)

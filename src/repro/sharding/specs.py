@@ -80,13 +80,13 @@ def lm_param_specs(cfg: LMConfig, mesh, *, mode: str = "train") -> Dict[str, Any
 
 
 def serving_arena_spec() -> P:
-    """Paged KV arena (n_pages, page_size, L, Hkv, Dh): kv heads over the
+    """Paged KV arena (n_pages, L, Hkv, page_size, Dh): kv heads over the
     model axis — the same head split as wk/wv, so the decode gather and
     the per-layer arena scatters stay local to each device's plane.
     Pages/slots replicate (slot tables are host-side numpy and
     device-agnostic: one logical page id addresses every device's slice
     of that page)."""
-    return P(None, None, None, "model", None)
+    return P(None, None, "model", None, None)
 
 
 def check_serving_divisibility(cfg: LMConfig, mesh) -> None:

@@ -229,8 +229,8 @@ def _check_paged_decode(seed: int) -> None:
     max_len = min(n_pages * page - page - 1, int(rng.integers(2, 40)))
     tables, lens, new_pages, new_slots = _random_layout(rng, n_pages, page, N, max_len)
     pg_ids, sl_pos = page_views(tables, lens, new_pages, new_slots, page)
-    ak = jnp.asarray(rng.normal(size=(n_pages, page, L, Hkv, D)), jnp.float32)
-    av = jnp.asarray(rng.normal(size=(n_pages, page, L, Hkv, D)), jnp.float32)
+    ak = jnp.asarray(rng.normal(size=(n_pages, L, Hkv, page, D)), jnp.float32)
+    av = jnp.asarray(rng.normal(size=(n_pages, L, Hkv, page, D)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(N, Hq, D)), jnp.float32)
     for layer in range(L):
         out = paged_decode_mha(

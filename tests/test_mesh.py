@@ -226,7 +226,7 @@ def test_sharded_decode_token_parity(tiny, tp, sched, kv_reuse):
     hkv = system.cfg.n_kv_heads
     for s in shards:
         assert s.data.shape[0] == engine.pool.n_pages   # pages replicated
-        assert s.data.shape[3] == hkv // msz            # kv heads split
+        assert s.data.shape[2] == hkv // msz            # kv heads split
 
 
 @needs_devices

@@ -77,7 +77,9 @@ def test_int8_store_arena_holds_dequantized_bytes():
     assert blk.scale_k is not None and blk.data_k.dtype == np.int8
     q, s = quantize_rows(k)
     np.testing.assert_array_equal(blk.host_k, dequantize_rows(q, s))
-    gk = np.asarray(pool.arena_k).reshape(-1, 2, 2, 4)[blk.slots]
+    # arena (P, L, Hkv, page, Dh) -> slot rows (P*page, L, Hkv, Dh)
+    rows = np.asarray(pool.arena_k).transpose(0, 3, 1, 2, 4)
+    gk = rows.reshape(-1, 2, 2, 4)[blk.slots]
     np.testing.assert_array_equal(gk, blk.host_k)
     assert store.dequant_s > 0.0
     # prefix tier: never quantized
