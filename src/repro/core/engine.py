@@ -30,6 +30,7 @@ from repro.kernels.flash_attention.ops import mha_flash
 from repro.kernels.selective_attention.ops import (build_block_liveness,
                                                   selective_mha)
 from repro.models import layers as L
+from repro.serving import tracing
 
 # Pallas tile sizes for the serving-path kernels: MXU-shaped, and the
 # smallest the TPU accepts for the key-mask blocks, whose key axis is
@@ -414,6 +415,7 @@ def _sel_attn(qr, k_l, v_l, cfg: LMConfig, r_pos, pos, valid, live):
     return full_attn_batched(qr, k_l, v_l, cfg, r_pos, pos, k_valid=valid)
 
 
+@jax.named_scope("selective_layers")
 def _selective_layers_impl(params, x, r_idx, r_valid, ck, cv, valid,
                            key_rot_pos, final_slot, cfg: LMConfig,
                            live, collect_kv: bool):
@@ -710,19 +712,28 @@ def selective_layers_batch(params, cfg: LMConfig, items,
         xs = [items[i][1] for i in idxs] + [zrow_x] * (B - len(idxs))
         cks = [items[i][3] for i in idxs] + [zrow_ck] * (B - len(idxs))
         cvs = [items[i][4] for i in idxs] + [zrow_ck] * (B - len(idxs))
-        args = (params, jnp.stack(xs),
-                jnp.asarray(r_idx_p), jnp.asarray(r_valid),
-                jnp.asarray(np.stack(cks)), jnp.asarray(np.stack(cvs)),
-                jnp.asarray(valid), jnp.arange(n_pad),
-                jnp.asarray(final_slot), cfg, jnp.asarray(live))
-        if return_kv:
-            logits, k_rest, v_rest = _jit_selective_layers_kv(*args)
-            k_rest = np.asarray(k_rest, np.float32)
-            v_rest = np.asarray(v_rest, np.float32)
-        else:
-            logits = _jit_selective_layers(*args)
-            k_rest = v_rest = None
-        logits = np.asarray(logits, np.float32)
+        with tracing.span("engine.finalize.h2d"):
+            host = (r_idx_p, r_valid, np.stack(cks), np.stack(cvs), valid,
+                    final_slot, live)
+            tracing.count("h2d_bytes", tracing.nbytes(*host))
+            r_idx_d, r_valid_d, ck_d, cv_d, valid_d, final_d, live_d = (
+                jnp.asarray(a) for a in host)
+            args = (params, jnp.stack(xs), r_idx_d, r_valid_d, ck_d, cv_d,
+                    valid_d, jnp.arange(n_pad), final_d, cfg, live_d)
+        with tracing.span("engine.finalize.run"):
+            out = (_jit_selective_layers_kv if return_kv
+                   else _jit_selective_layers)(*args)
+            jax.block_until_ready(out)
+        with tracing.span("engine.finalize.d2h"):
+            if return_kv:
+                logits, k_rest, v_rest = out
+                tracing.count("d2h_bytes", tracing.nbytes(k_rest, v_rest))
+                k_rest = np.asarray(k_rest, np.float32)
+                v_rest = np.asarray(v_rest, np.float32)
+            else:
+                logits, k_rest, v_rest = out, None, None
+            tracing.count("d2h_bytes", logits.nbytes)
+            logits = np.asarray(logits, np.float32)
         for bi, i in enumerate(idxs):
             kr = k_rest[bi] if return_kv else None
             vr = v_rest[bi] if return_kv else None
@@ -817,6 +828,7 @@ def selective_prefill_batch(
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnums=(8,))
+@jax.named_scope("layer0_chunk")
 def _jit_layer0_chunk(params, toks_c, offset, valid, ck0_c, cv0_c,
                       kbuf, vbuf, cfg: LMConfig):
     """One layer-0 chunk: queries [offset, offset+C) vs all scanned keys.
@@ -857,6 +869,7 @@ def _jit_layer0_chunk(params, toks_c, offset, valid, ck0_c, cv0_c,
 
 
 @jax.jit
+@jax.named_scope("mass_sum")
 def _jit_mass_sum(m):
     """Eq. 3 attention-mass finalize: sum the accumulated per-query rows
     over the query axis.  Must run through XLA — the monolithic layer 0
@@ -946,18 +959,23 @@ class ChunkedPrefill:
             raise RuntimeError("prompt fully scanned")
         off = self.offset
         C = self.next_chunk_tokens()
-        x_c, m_c, div_c, k0_c, v0_c, self.kbuf, self.vbuf = \
-            _jit_layer0_chunk(
-                self.params, jnp.asarray(self.toks[off:off + C]),
-                jnp.asarray(off, jnp.int32), jnp.asarray(self.valid),
-                jnp.asarray(self.ckp[off:off + C, 0]),
-                jnp.asarray(self.cvp[off:off + C, 0]),
-                self.kbuf, self.vbuf, self.cfg)
+        with tracing.span("engine.chunk.h2d"):
+            host = (self.toks[off:off + C], np.int32(off), self.valid,
+                    self.ckp[off:off + C, 0], self.cvp[off:off + C, 0])
+            tracing.count("h2d_bytes", tracing.nbytes(*host))
+            args = [jnp.asarray(a) for a in host]
+        with tracing.span("engine.chunk.run"):
+            x_c, m_c, div_c, k0_c, v0_c, self.kbuf, self.vbuf = \
+                _jit_layer0_chunk(self.params, *args, self.kbuf, self.vbuf,
+                                  self.cfg)
+            jax.block_until_ready(m_c)
         self._xs.append(x_c)
-        self._ms.append(np.asarray(m_c))
-        self._divs.append(np.asarray(div_c))
-        k0 = np.asarray(k0_c, np.float32)
-        v0 = np.asarray(v0_c, np.float32)
+        with tracing.span("engine.chunk.d2h"):
+            tracing.count("d2h_bytes", tracing.nbytes(m_c, div_c, k0_c, v0_c))
+            self._ms.append(np.asarray(m_c))
+            self._divs.append(np.asarray(div_c))
+            k0 = np.asarray(k0_c, np.float32)
+            v0 = np.asarray(v0_c, np.float32)
         self._k0s.append(k0)
         self._v0s.append(v0)
         self.offset = off + C
@@ -969,10 +987,14 @@ class ChunkedPrefill:
         return np.arange(lo, hi), k0[:hi - lo], v0[:hi - lo]
 
     def _select(self) -> None:
-        attn_mass = _jit_mass_sum(jnp.asarray(np.concatenate(self._ms)))
-        div = np.concatenate(self._divs)[:self.n_pad]
-        self.recompute, self.stats = select_recompute(
-            self.plan, self.have, np.asarray(attn_mass), div, self.sel)
+        with tracing.span("engine.select"):
+            ms = np.concatenate(self._ms)
+            tracing.count("h2d_bytes", ms.nbytes)
+            attn_mass = _jit_mass_sum(jnp.asarray(ms))
+            div = np.concatenate(self._divs)[:self.n_pad]
+            tracing.count("d2h_bytes", attn_mass.nbytes)
+            self.recompute, self.stats = select_recompute(
+                self.plan, self.have, np.asarray(attn_mass), div, self.sel)
         # the mass rows are O(n_pad^2) host floats per request and many
         # requests sit mid-scan concurrently — free them the moment the
         # scan-wide reduction has consumed them

@@ -137,5 +137,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((q_block, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(*args)
     return out[:, :sq]

@@ -180,6 +180,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, hkv, g_pad, d), q.dtype),
         interpret=interpret,
+        name="paged_decode",
     )
     return fn(
         page_ids.astype(jnp.int32),

@@ -208,5 +208,6 @@ def selective_attention(q: jax.Array, q_positions: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, r_p, d), q.dtype),
         interpret=interpret,
+        name="selective_attention",
     )(live.reshape(-1), qpos, q, k, v, hh)
     return out[:, :r]

@@ -55,6 +55,7 @@ from repro.kernels.paged_attention.ops import paged_decode_mha
 from repro.kernels.paged_attention.ref import masked_decode_attention_ref
 from repro.models import layers as L
 from repro.serving import block_store as BS
+from repro.serving import tracing
 from repro.serving.kv_pool import (
     KVExport,
     PagedKVPool,
@@ -192,6 +193,7 @@ def _decode_attn(q, k_l, v_l, kv_valid):
     return masked_decode_attention_ref(q, k_l, v_l, kv_valid)
 
 
+@jax.named_scope("decode_step")
 def _decode_step(
     params,
     toks,
@@ -779,65 +781,70 @@ class BatchEngine:
         self._check_plan(r)
         if r.rid in self.prefill_states:
             raise KeyError(f"request {r.rid} already prefilling")
+        with tracing.span("engine.admit", rid=r.rid):
+            self._begin_prefill(r)
+
+    def _begin_prefill(self, r: BatchRequest) -> None:
         plan, n = r.plan, r.plan.n
         ck, cv, have = r.cached_k, r.cached_v, r.have
         store = self.store
         held: List = []
         pos_parts, slot_parts = [], []
         pending_prefix = pending_user = None
-        if store is not None:
-            reuse = r.reuse if r.reuse is not None else BS.RequestReuse()
-            # --- prefix tier: inject a hit before compute ---
-            key = self._prefix_full_key(r)
-            if key is not None:
-                pblk = store.acquire(key)
-                if pblk is not None:
-                    held.append(key)
-                    npfx = min(pblk.n_tokens, n)
-                    ck = np.array(ck, np.float32)
-                    cv = np.array(cv, np.float32)
-                    have = have.copy()
-                    ck[:npfx] = pblk.host_k[:npfx]
-                    cv[:npfx] = pblk.host_v[:npfx]
-                    have[:npfx] = True
-                    pos_parts.append(np.arange(npfx))
-                    slot_parts.append(pblk.slots[:npfx])
-                else:
-                    pending_prefix = key
-            # --- item tier: offline bytes exist now, so misses insert
-            # at admission (later arrivals hit them; this request keeps
-            # its own private rows — the bytes are identical either way)
-            for ref in reuse.blocks:
-                blk = store.acquire(ref.key)
-                if blk is None and ref.k is not None:
-                    blk = store.insert(
-                        ref.key,
-                        BS.ITEM_TIER,
-                        ref.k,
-                        ref.v,
-                        tokens=ref.tokens,
-                        defer_write=True,
-                    )
-                    if blk is not None:
-                        blk.refcount += 1
-                if blk is not None:
-                    held.append(ref.key)
-                    pos_parts.append(ref.positions)
-                    slot_parts.append(blk.slots[ref.offsets])
-            # --- user tier (fresh bytes needed: miss inserts at finalize)
-            if reuse.user_key is not None:
-                u_pos = BS.user_reuse_positions(plan, r.have, reuse.prefix_end)
-                if len(u_pos):
-                    ublk = store.acquire(reuse.user_key)
-                    if ublk is not None:
-                        held.append(reuse.user_key)
-                        common = np.intersect1d(u_pos, ublk.positions)
-                        pos_parts.append(common)
-                        slot_parts.append(
-                            ublk.slots[np.searchsorted(ublk.positions, common)]
-                        )
+        with tracing.span("engine.admit.store", rid=r.rid):
+            if store is not None:
+                reuse = r.reuse if r.reuse is not None else BS.RequestReuse()
+                # --- prefix tier: inject a hit before compute ---
+                key = self._prefix_full_key(r)
+                if key is not None:
+                    pblk = store.acquire(key)
+                    if pblk is not None:
+                        held.append(key)
+                        npfx = min(pblk.n_tokens, n)
+                        ck = np.array(ck, np.float32)
+                        cv = np.array(cv, np.float32)
+                        have = have.copy()
+                        ck[:npfx] = pblk.host_k[:npfx]
+                        cv[:npfx] = pblk.host_v[:npfx]
+                        have[:npfx] = True
+                        pos_parts.append(np.arange(npfx))
+                        slot_parts.append(pblk.slots[:npfx])
                     else:
-                        pending_user = (reuse.user_key, u_pos)
+                        pending_prefix = key
+                # --- item tier: offline bytes exist now, so misses insert
+                # at admission (later arrivals hit them; this request keeps
+                # its own private rows — the bytes are identical either way)
+                for ref in reuse.blocks:
+                    blk = store.acquire(ref.key)
+                    if blk is None and ref.k is not None:
+                        blk = store.insert(
+                            ref.key,
+                            BS.ITEM_TIER,
+                            ref.k,
+                            ref.v,
+                            tokens=ref.tokens,
+                            defer_write=True,
+                        )
+                        if blk is not None:
+                            blk.refcount += 1
+                    if blk is not None:
+                        held.append(ref.key)
+                        pos_parts.append(ref.positions)
+                        slot_parts.append(blk.slots[ref.offsets])
+                # --- user tier (fresh bytes needed: miss inserts at finalize)
+                if reuse.user_key is not None:
+                    u_pos = BS.user_reuse_positions(plan, r.have, reuse.prefix_end)
+                    if len(u_pos):
+                        ublk = store.acquire(reuse.user_key)
+                        if ublk is not None:
+                            held.append(reuse.user_key)
+                            common = np.intersect1d(u_pos, ublk.positions)
+                            pos_parts.append(common)
+                            slot_parts.append(
+                                ublk.slots[np.searchsorted(ublk.positions, common)]
+                            )
+                        else:
+                            pending_user = (reuse.user_key, u_pos)
         mapped_pos = np.concatenate(pos_parts) if pos_parts else np.zeros(0, np.int64)
         mapped_slots = (
             np.concatenate(slot_parts) if slot_parts else np.zeros(0, np.int64)
@@ -849,25 +856,27 @@ class BatchEngine:
         n_priv = max(total_slots - len(mapped_pos), 0)
         begin_need = -(-n_priv // self.pool.page_size)
         extra = max(bound - begin_need, 0)
-        if store is not None and self.pool.free_pages < begin_need + extra:
-            store.evict_for(begin_need + extra)
-        try:
-            self.pool.alloc_mapped(
-                r.rid, n + r.n_reserve, mapped_pos, mapped_slots,
-                extra_pages=extra,
-            )
-        except PoolExhausted:
-            if store is not None:
-                store.release_all(held)
-            raise
+        with tracing.span("engine.admit.alloc", rid=r.rid):
+            if store is not None and self.pool.free_pages < begin_need + extra:
+                store.evict_for(begin_need + extra)
+            try:
+                self.pool.alloc_mapped(
+                    r.rid, n + r.n_reserve, mapped_pos, mapped_slots,
+                    extra_pages=extra,
+                )
+            except PoolExhausted:
+                if store is not None:
+                    store.release_all(held)
+                raise
         if store is not None:
             self.store_refs[r.rid] = held
         mapped_mask = np.zeros(n, bool)
         mapped_mask[mapped_pos[mapped_pos < n].astype(np.int64)] = True
-        cp = ENG.ChunkedPrefill(
-            self.params, self.cfg, plan, ck, cv, have, self.sel,
-            chunk_tokens=self.chunk_tokens, bucket=self.bucket,
-        )
+        with tracing.span("engine.admit.context", rid=r.rid):
+            cp = ENG.ChunkedPrefill(
+                self.params, self.cfg, plan, ck, cv, have, self.sel,
+                chunk_tokens=self.chunk_tokens, bucket=self.bucket,
+            )
         self.prefill_states[r.rid] = PrefillState(
             req=r,
             cp=cp,
@@ -1071,6 +1080,10 @@ class BatchEngine:
         deep-layer pool scatter for the whole batch; the layer-0 plane
         already landed incrementally as chunks completed.
         """
+        with tracing.span("engine.finalize", rid=",".join(map(str, rids))):
+            return self._finalize_prefill(rids)
+
+    def _finalize_prefill(self, rids: Sequence[int]) -> Dict[int, np.ndarray]:
         states = [self.prefill_states[rid] for rid in rids]
         sel_out = ENG.selective_layers_batch(
             self.params, self.cfg, [st.cp.sel_item() for st in states]
@@ -1082,25 +1095,31 @@ class BatchEngine:
             n = cp.n
             stats = cp.stats
             self.last_stats[r.rid] = stats
-            k_all = np.concatenate([cp.k0_full()[:, None], k_rest[:n]], axis=1)
-            v_all = np.concatenate([cp.v0_full()[:, None], v_rest[:n]], axis=1)
             rec = stats.recompute_mask
+            with tracing.span("engine.finalize.assemble", rid=r.rid):
+                k_all = np.concatenate([cp.k0_full()[:, None], k_rest[:n]], axis=1)
+                v_all = np.concatenate([cp.v0_full()[:, None], v_rest[:n]], axis=1)
             for positions, k0, v0 in st.l0_buf:  # lazy-mode chunk rows
                 entries_l0.append((r.rid, positions, k0, v0))
             if self.store is not None:
-                remap = self._finalize_store(st, k_all, v_all, rec)
+                with tracing.span("engine.finalize.store", rid=r.rid):
+                    remap = self._finalize_store(st, k_all, v_all, rec)
                 if len(remap):
                     # un-shared positions never got the incremental
                     # layer-0 write (they were mapped then) — their
                     # fresh plane lands with the finalize scatter
                     entries_l0.append((r.rid, remap, k_all[remap, 0], v_all[remap, 0]))
-            pos, kw, vw = self._selective_rows(r, stats, k_all, v_all)
-            keep = ~st.mapped_mask[pos]
-            entries_deep.append((r.rid, pos[keep], kw[keep][:, 1:], vw[keep][:, 1:]))
+            with tracing.span("engine.finalize.assemble", rid=r.rid):
+                pos, kw, vw = self._selective_rows(r, stats, k_all, v_all)
+                keep = ~st.mapped_mask[pos]
+                entries_deep.append(
+                    (r.rid, pos[keep], kw[keep][:, 1:], vw[keep][:, 1:])
+                )
             out[r.rid] = logits
             del self.prefill_states[r.rid]
         if self.store is not None:
-            self.store.flush_writes()
+            with tracing.span("engine.finalize.store"):
+                self.store.flush_writes()
         self.pool.write_at_batch(entries_deep, deep=True)
         self.pool.write_at_batch(entries_l0, layer=0)
         return out
@@ -1173,7 +1192,8 @@ class BatchEngine:
                     continue
                 if c > left:
                     rep.oversized = True
-                positions, k0, v0 = st.cp.run_chunk()
+                with tracing.span("engine.chunk", rid=rid):
+                    positions, k0, v0 = st.cp.run_chunk()
                 keep = ~st.mapped_mask[positions]
                 if self.eager_kv_writes:
                     l0_entries.append((rid, positions[keep], k0[keep], v0[keep]))
@@ -1198,43 +1218,46 @@ class BatchEngine:
     # ------------------------------- decode --------------------------------
     def decode(self, rids: Sequence[int], last_tokens: Sequence[int]) -> np.ndarray:
         """One token for each running request.  -> logits (N, V)."""
+        with tracing.span("engine.decode", rid=",".join(map(str, rids))):
+            return self._decode(rids, last_tokens)
+
+    def _decode(self, rids: Sequence[int], last_tokens: Sequence[int]) -> np.ndarray:
         n = len(rids)
         n_pad = -(-n // self.decode_bucket) * self.decode_bucket
-        tables, lens = self.pool.batch_tables(rids)
-        pages, slots = self.pool.append_slots(rids)
-        toks = np.zeros(n_pad, np.int32)
-        toks[:n] = np.asarray(last_tokens, np.int32)
-        tables_p = np.zeros((n_pad, tables.shape[1]), np.int32)
-        tables_p[:n] = tables
-        lens_p = np.zeros(n_pad, np.int32)
-        lens_p[:n] = lens
-        pages_p = np.zeros(n_pad, np.int32)  # pad rows: scratch page 0
-        slots_p = np.zeros(n_pad, np.int32)
-        pages_p[:n], slots_p[:n] = pages, slots
-        if ENG.decode_uses_paged(self.cfg):
-            pg_ids, sl_pos = page_views(
-                tables_p, lens_p, pages_p, slots_p, self.pool.page_size
+        with tracing.span("engine.decode.prep"):
+            tables, lens = self.pool.batch_tables(rids)
+            pages, slots = self.pool.append_slots(rids)
+            toks = np.zeros(n_pad, np.int32)
+            toks[:n] = np.asarray(last_tokens, np.int32)
+            tables_p = np.zeros((n_pad, tables.shape[1]), np.int32)
+            tables_p[:n] = tables
+            lens_p = np.zeros(n_pad, np.int32)
+            lens_p[:n] = lens
+            pages_p = np.zeros(n_pad, np.int32)  # pad rows: scratch page 0
+            slots_p = np.zeros(n_pad, np.int32)
+            pages_p[:n], slots_p[:n] = pages, slots
+            if ENG.decode_uses_paged(self.cfg):
+                pg_ids, sl_pos = page_views(
+                    tables_p, lens_p, pages_p, slots_p, self.pool.page_size
+                )
+            else:
+                # dead inputs on the gather path; keep them tiny and
+                # shape-stable so they never force a retrace
+                pg_ids = np.zeros((n_pad, 1), np.int32)
+                sl_pos = np.full((n_pad, 1, self.pool.page_size), -1, np.int32)
+        with tracing.span("engine.decode.h2d"):
+            host = (toks, tables_p, lens_p, pages_p, slots_p, pg_ids, sl_pos)
+            tracing.count("h2d_bytes", tracing.nbytes(*host))
+            args = [jnp.asarray(a) for a in host]
+        with tracing.span("engine.decode.run"):
+            logits, ak, av = _decode_step_jit(self.donate)(
+                self.params, *args, self.pool.arena_k, self.pool.arena_v, self.cfg
             )
-        else:
-            # dead inputs on the gather path; keep them tiny and
-            # shape-stable so they never force a retrace
-            pg_ids = np.zeros((n_pad, 1), np.int32)
-            sl_pos = np.full((n_pad, 1, self.pool.page_size), -1, np.int32)
-        logits, ak, av = _decode_step_jit(self.donate)(
-            self.params,
-            jnp.asarray(toks),
-            jnp.asarray(tables_p),
-            jnp.asarray(lens_p),
-            jnp.asarray(pages_p),
-            jnp.asarray(slots_p),
-            jnp.asarray(pg_ids),
-            jnp.asarray(sl_pos),
-            self.pool.arena_k,
-            self.pool.arena_v,
-            self.cfg,
-        )
+            jax.block_until_ready(logits)
         self.pool.update_arenas(ak, av)
-        return np.asarray(logits, np.float32)[:n]
+        with tracing.span("engine.decode.d2h"):
+            tracing.count("d2h_bytes", logits.nbytes)
+            return np.asarray(logits, np.float32)[:n]
 
     def release(self, rid: int) -> None:
         """Free a request's private pages and drop its shared-block

@@ -40,9 +40,11 @@ logic scales from one backend (`ContinuousBatcher`) to K concurrent
 backends behind a dispatch policy (`ClusterBatcher`): per-worker
 clocks, per-worker KV-pool backpressure, one shared arrival stream.
 `serving.cluster` plugs the Eq. 2 affinity router into the dispatch
-hook.  Every worker keeps a per-tick `TickRecord` log (token charges by
-kind, wall seconds), which is what the budget property test and the
-launcher's latency attribution read.
+hook.  Under the chunked discipline every worker keeps a per-tick
+`TickRecord` log: token charges by kind, the tick's wall seconds and,
+from the spans and counters of `serving.tracing`, where those seconds
+went (host phases, bytes crossing to and from the device, compiles)
+and how long each request started in it had waited.
 """
 
 from __future__ import annotations
@@ -51,10 +53,11 @@ import bisect
 import math
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Protocol, Sequence
+from typing import Callable, Dict, List, Optional, Protocol, Sequence, Tuple
 
 import numpy as np
 
+from repro.serving import tracing
 from repro.serving.api import GREEDY, SamplingParams, match_stop, sample_token
 from repro.serving.kv_pool import PoolExhausted
 
@@ -110,14 +113,25 @@ class Completion:
 
 @dataclass(frozen=True)
 class TickRecord:
-    """One scheduling tick's token accounting (chunked sched)."""
+    """One scheduling tick's accounting (chunked sched)."""
 
     t: float  # clock when the tick completed
-    seconds: float  # backend-reported wall/virtual step time
+    seconds: float  # wall seconds of the whole tick, admission included
     decode_tokens: int
     chunk_tokens: int
     finalize_tokens: int
     oversized: bool  # a single indivisible item exceeded the budget
+    # the tick's start on the server's clock (None off the server)
+    wall_s: Optional[float] = None
+    # self seconds by `tracing.span` name; they sum to at most `seconds`
+    phase_s: Tuple[Tuple[str, float], ...] = ()
+    h2d_bytes: int = 0  # host -> device bytes the tick's spans counted
+    d2h_bytes: int = 0  # device -> host
+    # (function, seconds) JAX spent tracing, lowering and compiling
+    compile_s: Tuple[Tuple[str, float], ...] = ()
+    # (rid, wall_s - arrival_s) for each request whose first chunk ran
+    waits: Tuple[Tuple[int, float], ...] = ()
+    n_finalized: int = 0  # requests whose prefill finished in the tick
 
 
 class EngineBackend(Protocol):
@@ -507,6 +521,7 @@ class WorkerState:
         chunk_tokens: int = 128,
         step_tokens: Optional[int] = None,
         role: str = "unified",
+        now: Optional[Callable[[], float]] = None,
     ):
         if sched not in ("wave", "chunked"):
             raise ValueError(f"unknown sched {sched!r}")
@@ -546,6 +561,9 @@ class WorkerState:
             if step_tokens is not None
             else max(4 * chunk_tokens, 512)
         )
+        # the server's wall clock, when a server drives this worker:
+        # `TickRecord.wall_s` and `.waits` are read on it
+        self.now = now
         self.clock = 0.0
         self.busy_seconds = 0.0  # step time only, no idle gaps
         self.preempted = 0  # decode-time pool-pressure victims
@@ -762,7 +780,37 @@ class WorkerState:
     def _step_chunked(self) -> None:
         """One unified tick: admit what fits, then run one budgeted
         engine step packing decode tokens for every running request
-        plus prefill chunks/finalizes for the admitted set."""
+        plus prefill chunks/finalizes for the admitted set.  The tick's
+        spans, counters and compiles land on its `TickRecord`."""
+        wall_s = self.now() if self.now is not None else None
+        with tracing.Tick() as acc:
+            ran = self._tick_chunked()
+        if ran is None:
+            return
+        rep, arrival = ran
+        waits = () if wall_s is None else tuple(
+            (rid, wall_s - arrival[rid]) for rid in rep.started)
+        self.ticks.append(
+            TickRecord(
+                t=self.clock,
+                seconds=acc.seconds,
+                decode_tokens=rep.charge_decode,
+                chunk_tokens=rep.charge_chunks,
+                finalize_tokens=rep.charge_finalize,
+                oversized=rep.oversized,
+                wall_s=wall_s,
+                phase_s=tuple(acc.phase_s.items()),
+                h2d_bytes=acc.counts["h2d_bytes"],
+                d2h_bytes=acc.counts["d2h_bytes"],
+                compile_s=tuple(acc.compile_s().items()),
+                waits=waits,
+                n_finalized=len(rep.finalized),
+            )
+        )
+
+    def _tick_chunked(self):
+        """The tick's work.  -> None when nothing ran, else (StepReport,
+        arrival time by rid of the requests prefilling in it)."""
         self.clock = self.ready_time()
         self._accept_inbound()
         self._admit_chunked()
@@ -800,16 +848,7 @@ class WorkerState:
             self._decode_s_per_step = self._ewma(
                 self._decode_s_per_step, dt * rep.charge_decode / charge
             )
-        self.ticks.append(
-            TickRecord(
-                t=self.clock,
-                seconds=dt,
-                decode_tokens=rep.charge_decode,
-                chunk_tokens=rep.charge_chunks,
-                finalize_tokens=rep.charge_finalize,
-                oversized=rep.oversized,
-            )
-        )
+        arrival = {r.rid: r.arrival_s for r in self.prefilling}
         if rep.decode_logits is not None:
             for e in db:
                 e.steps_left -= 1
@@ -850,6 +889,7 @@ class WorkerState:
                     continue  # a decode worker owns it now
                 self._last_tok_t[req.rid] = self.clock
                 self.decoding[req.rid] = entry
+        return rep, arrival
 
     def _admit_chunked(self) -> None:
         """Move due arrivals into the prefilling set, FIFO, while pool

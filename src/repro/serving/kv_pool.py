@@ -26,8 +26,9 @@ block's run into one scatter; the selective engine merges the
 recomputed tokens' fresh KV host-side and inserts whole *batches* with
 `write_at_batch` — one arena update per batch instead of one per span.
 
-Host-side writes use eager ``.at[].set`` (a full-arena copy per call on
-CPU, which is why fusing matters); the decode hot loop instead threads
+Host-side writes go through one jitted, undonated scatter
+(`_jit_pool_scatter`: a full-arena copy per call, which is why fusing
+matters); the decode hot loop instead threads
 the arenas through the jitted decode step (`serving.batch_engine`) and
 installs the returned buffers, so the new tokens' KV lands in-step (the
 arenas are donated on TPU/GPU, making the update in-place; CPU lacks
@@ -35,6 +36,7 @@ donation and copies).
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -44,6 +46,7 @@ import numpy as np
 
 from repro.configs.base import LMConfig
 from repro.core.assembly import RECOMPUTE, AssemblyPlan, plan_spans
+from repro.serving import tracing
 
 
 class PoolExhausted(RuntimeError):
@@ -71,6 +74,16 @@ def _pad_scatter(pages, slots, k, v):
     slots = np.concatenate([slots, np.zeros(extra, slots.dtype)])
     zrow = np.zeros((extra,) + k.shape[1:], k.dtype)
     return pages, slots, np.concatenate([k, zrow]), np.concatenate([v, zrow])
+
+
+@functools.partial(jax.jit, static_argnums=(6,))
+def _jit_pool_scatter(arena_k, arena_v, pages, slots, k, v, planes):
+    """Rows `k`/`v` into (page, slot) of both arenas, in the layer planes
+    `planes`: "all", "deep" (1..L-1) or one layer index."""
+    with jax.named_scope("pool_scatter"):
+        sel = {"all": slice(None), "deep": slice(1, None)}.get(planes, planes)
+        return (arena_k.at[pages, sel, :, slots].set(k),
+                arena_v.at[pages, sel, :, slots].set(v))
 
 
 @dataclass(frozen=True)
@@ -158,7 +171,7 @@ class PagedKVPool:
             # per-device arena planes: each device holds every page but
             # only its slice of the kv-head axis (the wk/wv head split).
             # Slot tables and page bookkeeping below stay host-side numpy
-            # and device-agnostic; eager `.at[].set` scatters and decode
+            # and device-agnostic; the jitted pool scatter and decode
             # gathers on the placed arenas preserve this sharding, so no
             # write/read path changes
             from repro.sharding.specs import serving_arena_spec
@@ -381,7 +394,7 @@ class PagedKVPool:
         unique within an entry (duplicate physical slots across a single
         scatter have undefined write order under XLA).  Entries with no
         positions are skipped (a fully store-mapped request writes
-        nothing).  Arena updates are eager copies on CPU (`.at[].set`),
+        nothing).  Each arena update copies the arena (no donation),
         so fusing a batch's insertions into one scatter is what makes
         the batched prefill's pool insertion O(1) copies instead of
         O(requests · spans).
@@ -404,20 +417,17 @@ class PagedKVPool:
                                      int(positions.max()) + 1)
         if not pages_all:
             return
-        pages = np.concatenate(pages_all)
-        slots = np.concatenate(slots_all)
-        k = np.concatenate(ks)
-        v = np.concatenate(vs)
+        with tracing.span("pool.write"):
+            self._scatter(np.concatenate(pages_all), np.concatenate(slots_all),
+                          np.concatenate(ks), np.concatenate(vs),
+                          "deep" if deep else ("all" if layer is None else layer))
+
+    def _scatter(self, pages, slots, k, v, planes) -> None:
+        """Pad to the row bucket, upload, and dispatch the arena scatter."""
         pages, slots, k, v = _pad_scatter(pages, slots, k, v)
-        if deep:
-            self.arena_k = self.arena_k.at[pages, 1:, :, slots].set(k)
-            self.arena_v = self.arena_v.at[pages, 1:, :, slots].set(v)
-        elif layer is None:
-            self.arena_k = self.arena_k.at[pages, :, :, slots].set(k)
-            self.arena_v = self.arena_v.at[pages, :, :, slots].set(v)
-        else:
-            self.arena_k = self.arena_k.at[pages, layer, :, slots].set(k)
-            self.arena_v = self.arena_v.at[pages, layer, :, slots].set(v)
+        tracing.count("h2d_bytes", tracing.nbytes(pages, slots, k, v))
+        self.arena_k, self.arena_v = _jit_pool_scatter(
+            self.arena_k, self.arena_v, pages, slots, k, v, planes)
 
     def write_slots(self, slot_ids: np.ndarray,
                     k: np.ndarray, v: np.ndarray) -> None:
@@ -427,21 +437,19 @@ class PagedKVPool:
 
     def write_slots_batch(self, entries: Sequence[tuple]) -> None:
         """Fused multi-block physical-slot scatter: ONE arena update for
-        any number of (slot_ids, k, v) writes.  Arena updates are eager
-        full copies on CPU, so the store flushes a whole prefill batch's
+        any number of (slot_ids, k, v) writes.  Arena updates are full
+        copies, so the store flushes a whole prefill batch's
         block insertions through here instead of paying one copy per
         block."""
         if not entries:
             return
-        slot_ids = np.concatenate(
-            [np.asarray(s, np.int64) for s, _, _ in entries])
-        k = np.concatenate([np.asarray(k) for _, k, _ in entries])
-        v = np.concatenate([np.asarray(v) for _, _, v in entries])
-        pages = slot_ids // self.page_size
-        slots = slot_ids % self.page_size
-        pages, slots, k, v = _pad_scatter(pages, slots, k, v)
-        self.arena_k = self.arena_k.at[pages, :, :, slots].set(k)
-        self.arena_v = self.arena_v.at[pages, :, :, slots].set(v)
+        with tracing.span("pool.write"):
+            slot_ids = np.concatenate(
+                [np.asarray(s, np.int64) for s, _, _ in entries])
+            k = np.concatenate([np.asarray(k) for _, k, _ in entries])
+            v = np.concatenate([np.asarray(v) for _, _, v in entries])
+            self._scatter(slot_ids // self.page_size, slot_ids % self.page_size,
+                          k, v, "all")
 
     def write_prompt(self, rid: int, k: np.ndarray, v: np.ndarray) -> None:
         """Insert a full prompt cache (n, L, Hkv, Dh) starting at slot 0."""

@@ -24,8 +24,13 @@ Event flow, one tick::
               publish: new tokens in backend.generated[rid]
                        ──▶ per-session asyncio queues (StreamEvent)
                        new worker.done entries ──▶ api.Completion
-              metrics.tick(): rolling p50/p99 TTFT+TBT, queue
-                       depth, pool occupancy, store hit rates
+
+Each of the three phases is a `tracing.span` (``rcllm.server.admit``,
+``.step``, ``.publish``).  The worker gets the server's clock, so each
+`batching.TickRecord` carries the tick's start and the wall-clock queue
+wait of every request whose first chunk ran in it.  `metrics_snapshot`
+computes the rolling TTFT/TBT percentiles, queue depth, pool occupancy
+and store hit rates on demand.
 
 The worker's state is touched *only* between steps, by the scheduler
 task — `submit`/`cancel` just enqueue.  The engine step itself runs in
@@ -54,6 +59,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.serving import tracing
 from repro.serving.api import (
     Completion,
     ServeConfig,
@@ -196,10 +202,10 @@ class AsyncSessionServer:
             sched=config.sched,
             chunk_tokens=config.chunk_tokens,
             step_tokens=config.step_tokens,
+            now=self.now,
         )
         self.backend = backend
         self.metrics = OnlineMetrics()
-        self.metrics_log: deque = deque(maxlen=4096)
         self._sessions: Dict[int, Session] = {}
         self._arrivals: deque = deque()  # sessions awaiting admission
         self._cancels: set = set()
@@ -281,8 +287,9 @@ class AsyncSessionServer:
     async def _loop(self) -> None:
         worker = self.worker
         while self._running:
-            self._admit_arrivals()
-            self._apply_cancels()
+            with tracing.span("server.admit"):
+                self._admit_arrivals()
+                self._apply_cancels()
             if not worker.has_work():
                 # idle: park until a submit/cancel kicks the loop
                 self._kick.clear()
@@ -292,15 +299,16 @@ class AsyncSessionServer:
             try:
                 # the engine step runs in a thread so the event loop
                 # keeps accepting submissions mid-step
-                await asyncio.to_thread(worker.step)
+                with tracing.span("server.step"):
+                    await asyncio.to_thread(worker.step)
             except RuntimeError as e:
                 if "never be admitted" not in str(e):
                     raise
                 # head-of-queue request can never be admitted (pool too
                 # small even empty): reject that session, keep serving
                 self._reject_head()
-            self._publish()
-            self.metrics_log.append(self.metrics.snapshot(self))
+            with tracing.span("server.publish"):
+                self._publish()
 
     def _admit_arrivals(self) -> None:
         worker = self.worker
@@ -454,8 +462,8 @@ def serve_trace(
     speed: float = 0.0,
 ) -> Tuple[Dict[int, Completion], AsyncSessionServer]:
     """Synchronous convenience: build a server, replay a trace, return
-    (completions by rid, the stopped server — its `worker`/`metrics_log`
-    hold the run's scheduling record)."""
+    (completions by rid, the stopped server — its `worker.ticks` and
+    `worker.done` hold the run's scheduling record)."""
     server = AsyncSessionServer(backend, config)
     completions = asyncio.run(replay(server, submits, speed=speed))
     return completions, server
