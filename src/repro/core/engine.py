@@ -31,6 +31,7 @@ from repro.kernels.selective_attention.ops import (build_block_liveness,
                                                   selective_mha)
 from repro.models import layers as L
 from repro.serving import tracing
+from repro.serving.kv_pool import row_bucket
 
 # Pallas tile sizes for the serving-path kernels: MXU-shaped, and the
 # smallest the TPU accepts for the key-mask blocks, whose key axis is
@@ -344,6 +345,101 @@ def _pad_to(x: np.ndarray, n: int, fill=0):
                                       x.dtype)])
 
 
+@functools.partial(jax.jit, static_argnums=(0,))
+@jax.named_scope("context_kv")
+def _jit_context_kv(shape, row_sets):
+    """Zero float32 planes of `shape` with each (pos, k, v) row set
+    placed in turn; a position past the planes is a pad row, dropped."""
+    ck = jnp.zeros(shape, jnp.float32)
+    cv = jnp.zeros(shape, jnp.float32)
+    for pos, k, v in row_sets:
+        ck = ck.at[pos].set(k.astype(jnp.float32), mode="drop")
+        cv = cv.at[pos].set(v.astype(jnp.float32), mode="drop")
+    return ck, cv
+
+
+def context_kv(cached_k: np.ndarray, cached_v: np.ndarray, have: np.ndarray,
+               n_pad: int, prefix: Optional[tuple] = None, device=None):
+    """The cached pre-RoPE K/V a selective prefill reads, built on the
+    device: (n_pad, L, Hkv, Dh) float32 planes, zero but for the client's
+    `have` rows and then `prefix` = (npfx, k_rows, v_rows), device rows
+    for positions [0, npfx) padded to a row bucket (a prefix-tier hit).
+
+    Only the `have` rows the prefix does not cover cross to the device,
+    padded to the row bucket.  A row outside both never reaches the
+    output: `have` is False there, so Eq. 3 masks its divergence and the
+    token is recomputed.
+    """
+    shape = (n_pad,) + cached_k.shape[1:]
+    row_sets = []
+    pos = np.where(have)[0]
+    if prefix is not None:
+        pos = pos[pos >= prefix[0]]
+    if len(pos):
+        t_pad = row_bucket(len(pos))
+        hpos = np.full(t_pad, n_pad, np.int32)
+        hpos[:len(pos)] = pos
+        hk = np.zeros((t_pad,) + shape[1:], np.float32)
+        hv = np.zeros((t_pad,) + shape[1:], np.float32)
+        hk[:len(pos)] = cached_k[pos]
+        hv[:len(pos)] = cached_v[pos]
+        tracing.count("h2d_bytes", tracing.nbytes(hpos, hk, hv))
+        row_sets.append(jax.device_put((hpos, hk, hv), device))
+    if prefix is not None:
+        npfx, pk, pv = prefix
+        ppos = np.arange(pk.shape[0], dtype=np.int32)
+        ppos[npfx:] = n_pad
+        tracing.count("h2d_bytes", ppos.nbytes)
+        row_sets.append((jax.device_put(ppos, device), pk, pv))
+    ck, cv = _jit_context_kv(shape, tuple(row_sets))
+    if device is not None:
+        ck, cv = jax.device_put((ck, cv), device)
+    return ck, cv
+
+
+@functools.partial(jax.jit, static_argnums=(5,))
+@jax.named_scope("take_rows")
+def _jit_take_rows(k0, v0, k_rest, v_rest, idx, deep: bool):
+    """Float32 rows `idx` of the layer-0 planes, with the deep layers
+    after them when ``deep``."""
+    k, v = k0[idx].astype(jnp.float32), v0[idx].astype(jnp.float32)
+    if not deep:
+        return k, v
+    return (jnp.concatenate([k[:, None], k_rest[idx]], axis=1),
+            jnp.concatenate([v[:, None], v_rest[idx]], axis=1))
+
+
+@dataclass
+class PrefillKV:
+    """One selective prefill's fresh pre-RoPE K/V on the device, indexed
+    by prompt position: the layer-0 plane `k0`/`v0` (n_pad, Hkv, Dh) and
+    the merged layers 1..L-1 `k_rest`/`v_rest` (n_pad, L-1, Hkv, Dh) —
+    the cached rows with every recomputed row written over them, which
+    is exactly what the paged pool keeps.  The serving engine scatters
+    these planes into the arena without a host copy
+    (`kv_pool.PagedKVPool.write_rows`)."""
+
+    k0: jax.Array
+    v0: jax.Array
+    k_rest: jax.Array
+    v_rest: jax.Array
+
+    def pull(self, positions: np.ndarray, deep: bool = True):
+        """Host float32 rows at `positions`: (t, L, Hkv, Dh), or the
+        layer-0 rows (t, Hkv, Dh) alone without ``deep`` — for a block
+        store insert, which keeps host bytes.  Only these rows cross,
+        padded to the row bucket."""
+        positions = np.asarray(positions, np.int64)
+        t = len(positions)
+        idx = np.zeros(row_bucket(t), np.int32)
+        idx[:t] = positions
+        tracing.count("h2d_bytes", idx.nbytes)
+        k, v = _jit_take_rows(self.k0, self.v0, self.k_rest, self.v_rest,
+                              idx, deep)
+        tracing.count("d2h_bytes", tracing.nbytes(k, v))
+        return np.asarray(k)[:t], np.asarray(v)[:t]
+
+
 # Eq. 3 divergence at most this share of the cached row's L1 norm is
 # rounding, not a different key.  A reused token's layer-0 K/V is
 # context-free, so it matches its cache exactly on one device; a sharded
@@ -633,8 +729,7 @@ def _selective_prefill(
     n = plan.n
     n_pad = ((n + bucket - 1) // bucket) * bucket
     toks = _pad_to(plan.tokens.astype(np.int32), n_pad)
-    ckp = _pad_to(cached_k.astype(np.float32), n_pad)
-    cvp = _pad_to(cached_v.astype(np.float32), n_pad)
+    ckp, cvp = context_kv(cached_k, cached_v, have_cache, n_pad)
     have = have_cache
     valid = np.zeros(n_pad, bool)
     valid[:n] = True
@@ -642,7 +737,7 @@ def _selective_prefill(
     # ---- layer 0 (jitted): full attention + Eq. 3 terms ----
     layer0 = _jit_layer0_kv if return_kv else _jit_layer0
     out0 = layer0(params, jnp.asarray(toks), jnp.asarray(valid),
-                  jnp.asarray(ckp[:, 0]), jnp.asarray(cvp[:, 0]), cfg)
+                  ckp[:, 0], cvp[:, 0], cfg)
     if return_kv:
         x, attn_mass, div_raw, k0_raw, v0 = out0
     else:
@@ -668,17 +763,17 @@ def _pow2(n: int) -> int:
 
 
 def selective_layers_batch(params, cfg: LMConfig, items,
-                           r_bucket: int = 64, return_kv: bool = True):
+                           r_bucket: int = 64):
     """Bucketed batched selective-layer pass (phase 2 of the selective
     prefill): requests are grouped by (padded length, padded recompute
     budget), stacked with the batch axis padded to the next power of
     two, and ONE jitted selective step runs per bucket.
 
     items: sequence of (plan, x (n_pad, D), recompute (n,), ckp, cvp)
-    with ckp/cvp padded to n_pad.  -> list of (logits (V,), k_rest,
-    v_rest) per item in input order (k_rest/v_rest are the merged
-    pre-RoPE layers 1..L-1, (n_pad, L-1, Hkv, Dh); None unless
-    ``return_kv``).
+    with ckp/cvp the device planes of `context_kv`.  -> list of (logits
+    (V,), k_rest, v_rest) per item in input order: the logits on the
+    host, k_rest/v_rest the merged pre-RoPE layers 1..L-1 (n_pad, L-1,
+    Hkv, Dh) left on the device.
 
     This is THE selective dispatch for every serving path — the wave
     batched prefill and the chunked unified-step finalize both land
@@ -707,43 +802,34 @@ def selective_layers_batch(params, cfg: LMConfig, items,
             valid[bi, :plan.n] = True
             final_slot[bi] = len(r_idx) - 1
         live = _liveness_for(cfg, r_idx_p, valid)
-        zrow_x = jnp.zeros_like(items[idxs[0]][1])
-        zrow_ck = np.zeros_like(items[idxs[0]][3])
-        xs = [items[i][1] for i in idxs] + [zrow_x] * (B - len(idxs))
-        cks = [items[i][3] for i in idxs] + [zrow_ck] * (B - len(idxs))
-        cvs = [items[i][4] for i in idxs] + [zrow_ck] * (B - len(idxs))
         with tracing.span("engine.finalize.h2d"):
-            host = (r_idx_p, r_valid, np.stack(cks), np.stack(cvs), valid,
-                    final_slot, live)
+            host = (r_idx_p, r_valid, valid, final_slot, live)
             tracing.count("h2d_bytes", tracing.nbytes(*host))
-            r_idx_d, r_valid_d, ck_d, cv_d, valid_d, final_d, live_d = (
+            r_idx_d, r_valid_d, valid_d, final_d, live_d = (
                 jnp.asarray(a) for a in host)
-            args = (params, jnp.stack(xs), r_idx_d, r_valid_d, ck_d, cv_d,
-                    valid_d, jnp.arange(n_pad), final_d, cfg, live_d)
+            pad = B - len(idxs)
+            xs, cks, cvs = ([items[i][j] for i in idxs]
+                            + [jnp.zeros_like(items[idxs[0]][j])] * pad
+                            for j in (1, 3, 4))
+            args = (params, jnp.stack(xs), r_idx_d, r_valid_d, jnp.stack(cks),
+                    jnp.stack(cvs), valid_d, jnp.arange(n_pad), final_d, cfg,
+                    live_d)
         with tracing.span("engine.finalize.run"):
-            out = (_jit_selective_layers_kv if return_kv
-                   else _jit_selective_layers)(*args)
-            jax.block_until_ready(out)
+            logits, k_rest, v_rest = _jit_selective_layers_kv(*args)
+            jax.block_until_ready(logits)
         with tracing.span("engine.finalize.d2h"):
-            if return_kv:
-                logits, k_rest, v_rest = out
-                tracing.count("d2h_bytes", tracing.nbytes(k_rest, v_rest))
-                k_rest = np.asarray(k_rest, np.float32)
-                v_rest = np.asarray(v_rest, np.float32)
-            else:
-                logits, k_rest, v_rest = out, None, None
             tracing.count("d2h_bytes", logits.nbytes)
             logits = np.asarray(logits, np.float32)
         for bi, i in enumerate(idxs):
-            kr = k_rest[bi] if return_kv else None
-            vr = v_rest[bi] if return_kv else None
-            results[i] = (logits[bi], kr, vr)
+            results[i] = (logits[bi],) + tuple(
+                jax.lax.dynamic_index_in_dim(a, bi, keepdims=False)
+                for a in (k_rest, v_rest))
     return results
 
 
 def selective_prefill_batch(
     params, cfg: LMConfig, items: Sequence, sel: SelectiveConfig,
-    bucket: int = 128, r_bucket: int = 64, return_kv: bool = True,
+    bucket: int = 128, r_bucket: int = 64,
 ):
     """Batched beyond-prefix prefill over many requests at once.
 
@@ -759,53 +845,35 @@ def selective_prefill_batch(
     O(#distinct buckets · log batch) regardless of how the continuous
     batcher composes batches, at ≤ 2× padded-row waste.
 
-    items: sequence of (plan, cached_k, cached_v, have) tuples.
-    -> list of (logits (V,), EngineStats, k_all (n, L, Hkv, Dh), v_all)
-    per request, in input order (k_all/v_all None unless ``return_kv``).
+    items: sequence of (plan, cached_k, cached_v, have, prefix) tuples
+    (`prefix`: `context_kv`'s prefix rows, or None).
+    -> list of (logits (V,), EngineStats, PrefillKV) per request, in
+    input order; the K/V stays on the device.
     """
     if not items:
         return []
     # ---- phase 1: per-request layer 0 + host-side Eq. 3 selection ----
-    x_of, rec_of, stats_of, k0_of, v0_of, ckp_of, cvp_of = (
-        {}, {}, {}, {}, {}, {}, {})
-    layer0 = _jit_layer0_kv if return_kv else _jit_layer0
-    for i, (plan, ck, cv, have) in enumerate(items):
+    sel_items, stats_of, l0_of = [], [], []
+    for plan, ck, cv, have, prefix in items:
         n_pad = ((plan.n + bucket - 1) // bucket) * bucket
         toks = _pad_to(plan.tokens.astype(np.int32), n_pad)
         valid = np.zeros(n_pad, bool)
         valid[:plan.n] = True
-        ckp = _pad_to(ck.astype(np.float32), n_pad)
-        cvp = _pad_to(cv.astype(np.float32), n_pad)
-        out0 = layer0(params, jnp.asarray(toks), jnp.asarray(valid),
-                      jnp.asarray(ckp[:, 0]), jnp.asarray(cvp[:, 0]), cfg)
-        if return_kv:
-            x, attn_mass, div_raw, k0, v0 = out0
-            k0_of[i] = np.asarray(k0, np.float32)
-            v0_of[i] = np.asarray(v0, np.float32)
-        else:
-            x, attn_mass, div_raw = out0
-            k0_of[i] = v0_of[i] = None
-        rec_of[i], stats_of[i] = select_recompute(
-            plan, have, attn_mass, div_raw, sel)
-        x_of[i] = x
-        ckp_of[i], cvp_of[i] = ckp, cvp
+        ckp, cvp = context_kv(ck, cv, have, n_pad, prefix)
+        x, attn_mass, div_raw, k0, v0 = _jit_layer0_kv(
+            params, jnp.asarray(toks), jnp.asarray(valid), ckp[:, 0],
+            cvp[:, 0], cfg)
+        rec, stats = select_recompute(plan, have, attn_mass, div_raw, sel)
+        sel_items.append((plan, x, rec, ckp, cvp))
+        stats_of.append(stats)
+        l0_of.append((k0, v0))
 
     # ---- phase 2: selective layers per (n_pad, r_pad) bucket ----
-    sel_items = [(items[i][0], x_of[i], rec_of[i], ckp_of[i], cvp_of[i])
-                 for i in range(len(items))]
     sel_out = selective_layers_batch(params, cfg, sel_items,
-                                     r_bucket=r_bucket, return_kv=return_kv)
-    results = []
-    for i, (logits, k_rest, v_rest) in enumerate(sel_out):
-        n = items[i][0].n
-        k_all = v_all = None
-        if return_kv:
-            k_all = np.concatenate(
-                [k0_of[i][:, None], k_rest], axis=1)[:n]
-            v_all = np.concatenate(
-                [v0_of[i][:, None], v_rest], axis=1)[:n]
-        results.append((logits, stats_of[i], k_all, v_all))
-    return results
+                                     r_bucket=r_bucket)
+    return [(logits, stats, PrefillKV(k0, v0, k_rest, v_rest))
+            for (logits, k_rest, v_rest), stats, (k0, v0)
+            in zip(sel_out, stats_of, l0_of)]
 
 
 # ---------------------------------------------------------------------------
@@ -827,27 +895,32 @@ def selective_prefill_batch(
 # monolithic XLA reduction bitwise (a host-side numpy sum does NOT).
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnums=(8,))
+@functools.partial(jax.jit, static_argnums=(10, 11))
 @jax.named_scope("layer0_chunk")
-def _jit_layer0_chunk(params, toks_c, offset, valid, ck0_c, cv0_c,
-                      kbuf, vbuf, cfg: LMConfig):
+def _jit_layer0_chunk(params, toks, offset, valid, ckp, cvp, kbuf, vbuf,
+                      k0buf, v0buf, C: int, cfg: LMConfig):
     """One layer-0 chunk: queries [offset, offset+C) vs all scanned keys.
 
-    toks_c: (C,) chunk token ids (0-padded past the prompt); offset:
+    toks: (nbuf,) prompt token ids (0-padded past the prompt); offset:
     scalar int32 (traced, so one compile serves every chunk index);
     valid: (nbuf,) key validity (True at real prompt positions);
-    ck0_c/cv0_c: (C, Hkv, Dh) cached layer-0 rows for Eq. 3 divergence;
-    kbuf/vbuf: (nbuf, Hkv, Dh) accumulated rotated-key / value buffers.
-    -> (x_c, m_c, div_c, k0_c, v0_c, kbuf', vbuf') where m_c (C, nbuf)
+    ckp/cvp: (nbuf, L, Hkv, Dh) cached K/V, whose layer-0 rows feed Eq. 3
+    divergence; kbuf/vbuf: (nbuf, Hkv, Dh) accumulated rotated-key /
+    value buffers; k0buf/v0buf: (nbuf, Hkv, Dh) float32 fresh pre-RoPE
+    layer-0 K/V by position.
+    -> (x_c, m_c, div_c, kbuf', vbuf', k0buf', v0buf') where m_c (C, nbuf)
     holds per-query head-mean attention probabilities (the Eq. 3 mass
-    rows) and k0_c/v0_c are the chunk's fresh pre-RoPE layer-0 KV.
+    rows).
 
     Unscanned keys (positions >= offset+C) are zeros in the buffers but
     causally invisible to every chunk query, so the standard causal +
     validity mask is exactly the monolithic mask.
     """
-    C = toks_c.shape[0]
     pos_c = offset + jnp.arange(C)
+    toks_c = jax.lax.dynamic_slice(toks, (offset,), (C,))
+    ck0_c, cv0_c = (
+        jax.lax.dynamic_slice(a, (offset, 0, 0, 0), (C, 1) + a.shape[2:])[:, 0]
+        for a in (ckp, cvp))
     x = params["embed"][toks_c].astype(jnp.dtype(cfg.dtype))
     if cfg.tie_embeddings:
         x = x * (cfg.d_model ** 0.5)
@@ -856,6 +929,10 @@ def _jit_layer0_chunk(params, toks_c, offset, valid, ck0_c, cv0_c,
     q, k, k_raw, v = qkv_proj(h, lp, cfg, pos_c)
     kbuf = jax.lax.dynamic_update_slice(kbuf, k, (offset, 0, 0))
     vbuf = jax.lax.dynamic_update_slice(vbuf, v, (offset, 0, 0))
+    k0buf = jax.lax.dynamic_update_slice(
+        k0buf, k_raw.astype(k0buf.dtype), (offset, 0, 0))
+    v0buf = jax.lax.dynamic_update_slice(
+        v0buf, v.astype(v0buf.dtype), (offset, 0, 0))
     k_pos = jnp.arange(kbuf.shape[0])
     # layer-0 scoring needs materialized probabilities, so this always
     # takes the jnp path — same as the monolithic layer 0 (`_layer0_impl`)
@@ -865,7 +942,8 @@ def _jit_layer0_chunk(params, toks_c, offset, valid, ck0_c, cv0_c,
     m_c = (probs * qvalid[None, None, :, None]).mean(axis=(0, 1))
     x = x + jnp.einsum("she,hed->sd", o, lp["wo"])
     x = x + mlp_block(L.rms_norm(x, lp["mlp_norm"], cfg.norm_eps), lp, cfg)
-    return x, m_c, _divergence(k_raw, ck0_c, v, cv0_c), k_raw, v, kbuf, vbuf
+    return (x, m_c, _divergence(k_raw, ck0_c, v, cv0_c), kbuf, vbuf, k0buf,
+            v0buf)
 
 
 @jax.jit
@@ -887,6 +965,13 @@ class ChunkedPrefill:
     dispatch as the wave path (`selective_layers_batch`) — so chunked
     and monolithic prefill decode bitwise-identical tokens.
 
+    Every intermediate stays on the device (`device`, or JAX's default
+    placement): the cached K/V (`context_kv`), the key buffers, the
+    fresh layer-0 K/V, the residual stream and the Eq. 3 mass rows.  The
+    host uploads the prompt once and the chunk offsets, and pulls only
+    what Eq. 3 selection reads: the summed attention mass and the
+    divergence, n_pad floats each.
+
     The serving engine (`serving.batch_engine.PrefillState`) wraps this
     with pool/store bookkeeping; this class is pure compute + state.
     """
@@ -894,7 +979,8 @@ class ChunkedPrefill:
     def __init__(self, params, cfg: LMConfig, plan: AssemblyPlan,
                  cached_k: np.ndarray, cached_v: np.ndarray,
                  have: np.ndarray, sel: SelectiveConfig,
-                 chunk_tokens: int, bucket: int = 128):
+                 chunk_tokens: int, bucket: int = 128,
+                 prefix: Optional[tuple] = None, device=None):
         self.params = params
         self.cfg = cfg
         self.plan = plan
@@ -911,21 +997,24 @@ class ChunkedPrefill:
         # covers n_pad in `chunk`-wide steps with a ragged final chunk
         # (n_pad and chunk are both multiples of the 64-token engine
         # bucket, so tail widths stay on the same O(1) shape grid).
-        self.toks = _pad_to(plan.tokens.astype(np.int32), self.n_pad)
-        self.valid = np.zeros(self.n_pad, bool)
-        self.valid[:n] = True
-        self.ckp = _pad_to(cached_k.astype(np.float32), self.n_pad)
-        self.cvp = _pad_to(cached_v.astype(np.float32), self.n_pad)
-        Hkv, Dh = cfg.n_kv_heads, cfg.resolved_head_dim
+        toks = _pad_to(plan.tokens.astype(np.int32), self.n_pad)
+        valid = np.zeros(self.n_pad, bool)
+        valid[:n] = True
+        tracing.count("h2d_bytes", tracing.nbytes(toks, valid))
+        self.toks, self.valid = jax.device_put((toks, valid), device)
+        self.ckp, self.cvp = context_kv(cached_k, cached_v, have, self.n_pad,
+                                        prefix, device)
+        rows = (self.n_pad, cfg.n_kv_heads, cfg.resolved_head_dim)
         # the model's dtype, like the monolithic layer 0's keys
-        self.kbuf = jnp.zeros((self.n_pad, Hkv, Dh), jnp.dtype(cfg.dtype))
-        self.vbuf = jnp.zeros((self.n_pad, Hkv, Dh), jnp.dtype(cfg.dtype))
+        self.kbuf = jnp.zeros(rows, jnp.dtype(cfg.dtype), device=device)
+        self.vbuf = jnp.zeros(rows, jnp.dtype(cfg.dtype), device=device)
+        # float32, like the arena the rows land in (bf16 -> f32 is exact)
+        self.k0 = jnp.zeros(rows, jnp.float32, device=device)
+        self.v0 = jnp.zeros(rows, jnp.float32, device=device)
         self.offset = 0
         self._xs: list = []
         self._ms: list = []
         self._divs: list = []
-        self._k0s: list = []
-        self._v0s: list = []
         self.recompute: Optional[np.ndarray] = None
         self.stats: Optional[EngineStats] = None
 
@@ -950,52 +1039,41 @@ class ChunkedPrefill:
         r_count = int(self.recompute.sum())
         return max(64, -(-r_count // 64) * 64)
 
-    def run_chunk(self):
-        """Scan the next chunk.  -> (positions, k0_rows, v0_rows): the
-        real prompt positions covered and their fresh pre-RoPE layer-0
-        KV, ready for incremental pool insertion (empty on an all-pad
-        tail chunk).  Completing the scan finalizes Eq. 3 selection."""
+    def run_chunk(self) -> np.ndarray:
+        """Scan the next chunk.  -> the real prompt positions it covered
+        (empty on an all-pad tail chunk), whose fresh pre-RoPE layer-0
+        K/V is now in `k0`/`v0`, ready for incremental pool insertion.
+        Completing the scan finalizes Eq. 3 selection."""
         if self.scan_done:
             raise RuntimeError("prompt fully scanned")
         off = self.offset
         C = self.next_chunk_tokens()
-        with tracing.span("engine.chunk.h2d"):
-            host = (self.toks[off:off + C], np.int32(off), self.valid,
-                    self.ckp[off:off + C, 0], self.cvp[off:off + C, 0])
-            tracing.count("h2d_bytes", tracing.nbytes(*host))
-            args = [jnp.asarray(a) for a in host]
+        offset = np.int32(off)
+        tracing.count("h2d_bytes", offset.nbytes)
         with tracing.span("engine.chunk.run"):
-            x_c, m_c, div_c, k0_c, v0_c, self.kbuf, self.vbuf = \
-                _jit_layer0_chunk(self.params, *args, self.kbuf, self.vbuf,
-                                  self.cfg)
+            (x_c, m_c, div_c, self.kbuf, self.vbuf, self.k0,
+             self.v0) = _jit_layer0_chunk(
+                self.params, self.toks, offset, self.valid, self.ckp,
+                self.cvp, self.kbuf, self.vbuf, self.k0, self.v0, C,
+                self.cfg)
             jax.block_until_ready(m_c)
         self._xs.append(x_c)
-        with tracing.span("engine.chunk.d2h"):
-            tracing.count("d2h_bytes", tracing.nbytes(m_c, div_c, k0_c, v0_c))
-            self._ms.append(np.asarray(m_c))
-            self._divs.append(np.asarray(div_c))
-            k0 = np.asarray(k0_c, np.float32)
-            v0 = np.asarray(v0_c, np.float32)
-        self._k0s.append(k0)
-        self._v0s.append(v0)
+        self._ms.append(m_c)
+        self._divs.append(div_c)
         self.offset = off + C
-        lo, hi = off, min(off + C, self.n)
         if self.scan_done:
             self._select()
-        if hi <= lo:
-            return np.zeros(0, np.int64), k0[:0], v0[:0]
-        return np.arange(lo, hi), k0[:hi - lo], v0[:hi - lo]
+        return np.arange(off, max(min(off + C, self.n), off))
 
     def _select(self) -> None:
         with tracing.span("engine.select"):
-            ms = np.concatenate(self._ms)
-            tracing.count("h2d_bytes", ms.nbytes)
-            attn_mass = _jit_mass_sum(jnp.asarray(ms))
-            div = np.concatenate(self._divs)[:self.n_pad]
-            tracing.count("d2h_bytes", attn_mass.nbytes)
+            attn_mass = _jit_mass_sum(jnp.concatenate(self._ms))
+            div = jnp.concatenate(self._divs)
+            tracing.count("d2h_bytes", tracing.nbytes(attn_mass, div))
             self.recompute, self.stats = select_recompute(
-                self.plan, self.have, np.asarray(attn_mass), div, self.sel)
-        # the mass rows are O(n_pad^2) host floats per request and many
+                self.plan, self.have, np.asarray(attn_mass), np.asarray(div),
+                self.sel)
+        # the mass rows are O(n_pad^2) floats per request and many
         # requests sit mid-scan concurrently — free them the moment the
         # scan-wide reduction has consumed them
         self._ms = []
@@ -1006,16 +1084,18 @@ class ChunkedPrefill:
         chunk outputs — the selective pass's input."""
         return jnp.concatenate(self._xs)[:self.n_pad]
 
-    def k0_full(self) -> np.ndarray:
-        """Fresh pre-RoPE layer-0 K (n, Hkv, Dh) over the real prompt."""
-        return np.concatenate(self._k0s)[:self.n]
-
-    def v0_full(self) -> np.ndarray:
-        return np.concatenate(self._v0s)[:self.n]
-
     def sel_item(self) -> tuple:
         """This request's `selective_layers_batch` entry."""
         if self.recompute is None:
             raise RuntimeError("selective pass before scan completed")
         return (self.plan, self.x_full(), self.recompute, self.ckp,
                 self.cvp)
+
+    def to_device(self, device) -> None:
+        """Move every device buffer to `device`: a chunk-partial prefill
+        handed to another engine resumes where that engine's arena is."""
+        for name, val in list(vars(self).items()):
+            if isinstance(val, jax.Array):
+                setattr(self, name, jax.device_put(val, device))
+            elif name in ("_xs", "_ms", "_divs"):
+                setattr(self, name, [jax.device_put(a, device) for a in val])

@@ -9,10 +9,12 @@ Two jitted steps drive every request:
   (padded length, padded recompute budget), their plans and cached KV
   stacked, and one jitted layer-0 + one jitted selective step run per
   bucket — the same Eq. 3 scoring and layer stack as the single-request
-  engine, shared code, not a copy.  Either way the prompt's pre-RoPE KV
-  lands in the paged pool: cached spans are inserted block-granularly
-  from the assembly plan, then only the recomputed tokens' fresh KV is
-  scattered on top.
+  engine, shared code, not a copy.  The rcllm prefill's K/V never
+  crosses to the host: the merged planes (cached spans with the
+  recomputed tokens' fresh rows over them, `core.engine.PrefillKV`) are
+  scattered from the device straight into the paged pool; the host
+  names positions and slots, and pulls only the rows a block-store
+  insert keeps as host bytes.
 
 * **decode** — a single-token batched step that reads K/V *through the
   page tables*: one arena gather per step, keys realigned to their
@@ -107,9 +109,9 @@ class PrefillState:
     pending_prefix: Optional[tuple] = None
     pending_user: Optional[tuple] = None  # (key, u_pos)
     started: bool = False
-    # buffered layer-0 rows awaiting the finalize scatter (lazy mode):
-    # (positions, k0, v0) per completed chunk
-    l0_buf: List[tuple] = field(default_factory=list)
+    # positions whose layer-0 rows the finalize scatter still owes (lazy
+    # mode), one array per completed chunk; the rows are `cp.k0`/`cp.v0`
+    l0_buf: List[np.ndarray] = field(default_factory=list)
 
 
 @dataclass
@@ -123,15 +125,16 @@ class RequestKV:
     riding their content keys so a destination holding a digest pays
     zero transfer), the engine stats, and — for a chunk-partial handoff
     — the live `PrefillState` (owed prefix/user inserts, mapped-mask,
-    buffered layer-0 rows, chunk scan position), so `finalize_prefill`
-    can run on a *different* engine than `begin_prefill`.  The serving
-    layer adds the sampling watermarks (`session`: generated tokens,
-    rng state, stop criteria) before routing.
+    owed layer-0 positions, chunk scan position and its device buffers),
+    so `finalize_prefill` can run on a *different* engine than
+    `begin_prefill`.  The serving layer adds the sampling watermarks
+    (`session`: generated tokens, rng state, stop criteria) before
+    routing.
 
     The pool/store payloads are self-contained host bytes; a partial
-    handoff's `prefill.cp` additionally references the model params,
-    which migration assumes are replicated across workers (they are —
-    every cluster worker serves the same model).
+    handoff's `prefill.cp` holds device buffers, which the importing
+    engine moves to its own device, and is rebound to that engine's
+    params (every cluster worker serves the same model).
     """
 
     rid: int
@@ -308,17 +311,12 @@ def _decode_step_jit(donate: bool):
     )
 
 
-def _donates(arr) -> bool:
-    """Does the backend holding `arr` implement buffer donation?"""
-    return any(d.platform in ("tpu", "gpu") for d in arr.devices())
-
-
 class BatchEngine:
     """Multi-request prefill + paged continuous decode on real hardware.
 
     ``batched_selective`` switches the rcllm prefill between the bucketed
     batched path (`engine.selective_prefill_batch`, the default) and the
-    legacy per-request loop — kept for parity tests and the
+    same path run one request at a time — kept for parity tests and the
     `bench_attn_backend` batched-vs-loop comparison.
 
     ``store`` (a `block_store.SharedBlockStore` over this engine's pool)
@@ -371,14 +369,24 @@ class BatchEngine:
         # chunked prefill writes each chunk's fresh layer-0 KV into the
         # pool as it completes.  With arena donation (TPU/GPU) the write
         # is in-place and eager per-tick writes are the natural
-        # incremental mode; on CPU every eager scatter is a full-arena
-        # copy, so the rows are buffered host-side and fused into the
-        # finalize scatter instead — nothing reads a request's rows
-        # before its decode starts, so the two modes are byte-identical.
-        self.donate = _donates(self.pool.arena_k)
+        # incremental mode; on CPU every scatter is a full-arena copy, so
+        # the positions are buffered and written from the device plane
+        # with the finalize scatter instead — nothing reads a request's
+        # rows before its decode starts, so the two modes are
+        # byte-identical.
+        self.donate = self.pool.donate
         if eager_kv_writes is None:
             eager_kv_writes = self.donate
         self.eager_kv_writes = eager_kv_writes
+        # the device an arena placed on one device lives on, where a
+        # prefill's buffers go; None when the arena is left to JAX's
+        # default placement or spans a mesh (GSPMD places them), so the
+        # buffers are placed the same way and no program is compiled
+        # twice for a placement that changed
+        devs = self.pool.arena_k.devices()
+        self.device = (next(iter(devs))
+                       if self.pool.arena_k.committed and len(devs) == 1
+                       else None)
         self.store_refs: Dict[int, list] = {}
         self.last_stats: Dict[int, ENG.EngineStats] = {}
         self.prefill_states: Dict[int, PrefillState] = {}
@@ -393,7 +401,8 @@ class BatchEngine:
                 return self._prefill_selective_shared(reqs)
             if self.batched_selective:
                 return self._prefill_selective_batch(reqs)
-            return np.stack([self._prefill_selective(r) for r in reqs])
+            return np.concatenate([self._prefill_selective_batch([r])
+                                   for r in reqs])
         raise ValueError(mode)
 
     def admission_pages(self, r: BatchRequest) -> tuple:
@@ -438,94 +447,57 @@ class BatchEngine:
             raise ValueError(f"request {r.rid}: rcllm prefill needs a plan")
 
     @staticmethod
-    def _selective_rows(r: BatchRequest, stats: ENG.EngineStats, k_all, v_all):
-        """Final pool rows for one selectively-prefilled request.
-
-        Block-granular semantics with host-side merging: cached span
-        values first (one contiguous run per plan span), then the
-        recomputed tokens' fresh KV overwriting them — resolved *before*
-        the arena scatter so the fused write sees unique positions
-        (duplicate slots in one XLA scatter have undefined order).
-        -> (positions, k rows, v rows).
-        """
-        plan = r.plan
-        write = np.zeros(plan.n, bool)
+    def _deep_positions(plan: AssemblyPlan, rec: np.ndarray) -> np.ndarray:
+        """Positions whose layers 1..L-1 a selective prefill writes:
+        every cached span (one contiguous run per plan span) and every
+        recomputed token.  The merged planes already hold the cached
+        value or, where recomputed, the fresh one at each of them."""
+        write = np.array(rec, bool)
         for s in plan_spans(plan):
             if s.source != RECOMPUTE:
                 write[s.start : s.end] = True
-        kw = np.array(r.cached_k, np.float32)
-        vw = np.array(r.cached_v, np.float32)
-        rec = stats.recompute_mask
-        kw[rec] = k_all[rec]
-        vw[rec] = v_all[rec]
-        write |= rec
-        pos = np.where(write)[0]
-        return pos, kw[pos], vw[pos]
+        return np.where(write)[0]
 
-    def _insert_selective(
-        self,
-        r: BatchRequest,
-        stats: ENG.EngineStats,
-        k_all: np.ndarray,
-        v_all: np.ndarray,
-    ) -> None:
-        """Pool insertion for one selectively-prefilled request: one
-        fused scatter for cached spans + recomputed KV, and one for the
-        always-fresh layer-0 plane (HH identification runs layer 0 in
-        full, so its KV is exact for every token)."""
-        self.last_stats[r.rid] = stats
-        n = r.plan.n
-        self.pool.alloc(r.rid, n + r.n_reserve)
-        pos, kw, vw = self._selective_rows(r, stats, k_all, v_all)
-        self.pool.write_at(r.rid, pos, kw, vw)
-        self.pool.write_at(
-            r.rid, np.arange(n), k_all[:, 0], v_all[:, 0], layer=0
-        )
+    def _write_prefill(self, rid: int, plan: AssemblyPlan, rec: np.ndarray,
+                       kv: ENG.PrefillKV, mapped_mask: np.ndarray,
+                       l0_pos: np.ndarray) -> None:
+        """Land one selective prefill in the request's private slots,
+        straight from the device planes: the merged layers 1..L-1 at
+        every written position not mapped at a store slot, and the fresh
+        layer-0 plane (HH identification runs layer 0 in full, so its KV
+        is exact for every token) at `l0_pos`.  The index arrays are
+        padded to the planes' length, so neither program depends on how
+        many positions a request maps."""
+        n_pad = kv.k_rest.shape[0]
+        pos = self._deep_positions(plan, rec)
+        pos = pos[~mapped_mask[pos]]
+        self.pool.write_rows(rid, pos, kv.k_rest, kv.v_rest, deep=True,
+                             pad_to=n_pad)
+        self.pool.write_rows(rid, l0_pos, kv.k0, kv.v0, layer=0,
+                             pad_to=n_pad)
 
     def _prefill_selective_batch(self, reqs: Sequence[BatchRequest]) -> np.ndarray:
         """Batched rcllm prefill: bucketed stacked requests, one jitted
         selective step per bucket (`engine.selective_prefill_batch`),
-        then ONE fused pool scatter for the whole batch (plus one for
-        the layer-0 planes) instead of per-request arena copies."""
+        then each request's planes scattered into its pages."""
         for r in reqs:
             self._check_plan(r)
         results = ENG.selective_prefill_batch(
             self.params,
             self.cfg,
-            [(r.plan, r.cached_k, r.cached_v, r.have) for r in reqs],
+            [(r.plan, r.cached_k, r.cached_v, r.have, None) for r in reqs],
             self.sel,
             bucket=self.bucket,
         )
         out = []
-        entries, entries_l0 = [], []
-        for r, (logits, stats, k_all, v_all) in zip(reqs, results):
+        for r, (logits, stats, kv) in zip(reqs, results):
             self.last_stats[r.rid] = stats
             n = r.plan.n
             self.pool.alloc(r.rid, n + r.n_reserve)
-            pos, kw, vw = self._selective_rows(r, stats, k_all, v_all)
-            entries.append((r.rid, pos, kw, vw))
-            entries_l0.append((r.rid, np.arange(n), k_all[:, 0], v_all[:, 0]))
+            self._write_prefill(r.rid, r.plan, stats.recompute_mask, kv,
+                                np.zeros(n, bool), np.arange(n))
             out.append(logits)
-        self.pool.write_at_batch(entries)
-        self.pool.write_at_batch(entries_l0, layer=0)
         return np.stack(out)
-
-    def _prefill_selective(self, r: BatchRequest) -> np.ndarray:
-        """Legacy one-request-at-a-time selective prefill (parity and
-        benchmark reference for the batched path)."""
-        self._check_plan(r)
-        logits, stats, k_all, v_all = ENG.selective_prefill_with_kv(
-            self.params,
-            self.cfg,
-            r.plan,
-            r.cached_k,
-            r.cached_v,
-            r.have,
-            self.sel,
-            bucket=self.bucket,
-        )
-        self._insert_selective(r, stats, k_all, v_all)
-        return logits
 
     # --------------------------- shared insertion ---------------------------
     def _prefix_full_key(self, r: BatchRequest):
@@ -558,33 +530,45 @@ class BatchEngine:
         prefix_hits: Dict[int, tuple] = {}
         items_in = []
         for r in reqs:
-            ck, cv, have = r.cached_k, r.cached_v, r.have
+            have, prefix = r.have, None
             key = self._prefix_full_key(r)
-            blk = store.get(key) if key is not None else None
-            if blk is not None:
-                # held until release(rid); recorded via prefix_hits
-                blk.refcount += 1
-                prefix_hits[r.rid] = (key, blk)
-                npfx = min(blk.n_tokens, r.plan.n)
-                ck = np.array(ck, np.float32)
-                cv = np.array(cv, np.float32)
-                have = have.copy()
-                ck[:npfx] = blk.host_k[:npfx]
-                cv[:npfx] = blk.host_v[:npfx]
-                have[:npfx] = True
-            items_in.append((r.plan, ck, cv, have))
+            if key is not None:
+                blk = store.get(key)
+                if blk is not None:
+                    # held until release(rid); recorded via prefix_hits
+                    blk.refcount += 1
+                    prefix_hits[r.rid] = (key, blk)
+                    have = have.copy()
+                    have[: min(blk.n_tokens, r.plan.n)] = True
+                prefix = self._prefix_rows(r, blk)
+            items_in.append((r.plan, r.cached_k, r.cached_v, have, prefix))
         if self.batched_selective:
             results = ENG.selective_prefill_batch(
                 self.params, self.cfg, items_in, self.sel, bucket=self.bucket
             )
         else:
             results = [
-                ENG.selective_prefill_with_kv(
-                    self.params, self.cfg, *item, self.sel, bucket=self.bucket
-                )
+                ENG.selective_prefill_batch(
+                    self.params, self.cfg, [item], self.sel, bucket=self.bucket
+                )[0]
                 for item in items_in
             ]
         return self._insert_batch_shared(reqs, results, prefix_hits)
+
+    def _prefix_rows(self, r: BatchRequest, pblk) -> tuple:
+        """`engine.context_kv`'s prefix rows for a request that has a
+        prefix-tier key: a hit's rows gathered on the device from the
+        block's arena slots (its bytes ARE the recomputed rows).  A miss
+        (`pblk` None) gathers no row through the same programs, so the
+        programs a window runs do not depend on which requests hit."""
+        n_rows = min(r.reuse.prefix_len, r.plan.n)
+        npfx, slots = 0, np.zeros(0, np.int64)
+        if pblk is not None:
+            npfx = min(pblk.n_tokens, r.plan.n)
+            slots = pblk.slots[:npfx]
+            self.store.flush_writes()  # the block's bytes are in the arena
+        k, v = self.pool.gather_slots(slots, n_rows)
+        return npfx, k, v
 
     def _insert_batch_shared(self, reqs, results, prefix_hits=None) -> np.ndarray:
         """Map store hits, insert missing blocks, write the private rest.
@@ -629,8 +613,7 @@ class BatchEngine:
         bounds = {r.rid: self.admission_pages(r)[0] for r in reqs}
         remaining = sum(bounds.values())
         out = []
-        entries, entries_l0 = [], []
-        for r, (logits, stats, k_all, v_all) in zip(reqs, results):
+        for r, (logits, stats, kv) in zip(reqs, results):
             self.last_stats[r.rid] = stats
             n = r.plan.n
             rec = stats.recompute_mask
@@ -655,8 +638,7 @@ class BatchEngine:
                         pblk = store.insert(
                             key,
                             BS.PREFIX_TIER,
-                            k_all[:npfx],
-                            v_all[:npfx],
+                            *kv.pull(np.arange(npfx)),
                             pinned=True,
                             keep_free=remaining,
                             defer_write=True,
@@ -710,12 +692,7 @@ class BatchEngine:
                     if ublk is not None:
                         held[r.rid].append(reuse.user_key)
                     else:
-                        ku = np.concatenate(
-                            [k_all[u_pos, :1], r.cached_k[u_pos, 1:]], axis=1
-                        )
-                        vu = np.concatenate(
-                            [v_all[u_pos, :1], r.cached_v[u_pos, 1:]], axis=1
-                        )
+                        ku, vu = self._user_rows(r, kv, u_pos)
                         ublk = store.insert(
                             reuse.user_key,
                             BS.USER_TIER,
@@ -755,16 +732,19 @@ class BatchEngine:
             self.store_refs[r.rid] = held[r.rid]
             mapped_mask = np.zeros(n, bool)
             mapped_mask[mapped_pos] = True
-            pos, kw, vw = self._selective_rows(r, stats, k_all, v_all)
-            keep = ~mapped_mask[pos]
-            entries.append((r.rid, pos[keep], kw[keep], vw[keep]))
-            l0_pos = np.where(~mapped_mask)[0]
-            entries_l0.append((r.rid, l0_pos, k_all[l0_pos, 0], v_all[l0_pos, 0]))
+            self._write_prefill(r.rid, r.plan, rec, kv, mapped_mask,
+                                np.where(~mapped_mask)[0])
             out.append(logits)
         store.flush_writes()
-        self.pool.write_at_batch(entries)
-        self.pool.write_at_batch(entries_l0, layer=0)
         return np.stack(out)
+
+    @staticmethod
+    def _user_rows(r: BatchRequest, kv: ENG.PrefillKV, u_pos: np.ndarray):
+        """A user-tier block's host bytes: the fresh layer-0 rows (pulled
+        from the device) over the semantic cache's deep layers."""
+        k0, v0 = kv.pull(u_pos, deep=False)
+        return (np.concatenate([k0[:, None], r.cached_k[u_pos, 1:]], axis=1),
+                np.concatenate([v0[:, None], r.cached_v[u_pos, 1:]], axis=1))
 
     # ------------------------ chunk-resumable prefill ------------------------
     def begin_prefill(self, r: BatchRequest) -> None:
@@ -786,31 +766,28 @@ class BatchEngine:
 
     def _begin_prefill(self, r: BatchRequest) -> None:
         plan, n = r.plan, r.plan.n
-        ck, cv, have = r.cached_k, r.cached_v, r.have
+        have = r.have
         store = self.store
         held: List = []
         pos_parts, slot_parts = [], []
         pending_prefix = pending_user = None
+        pfx_key = pblk = None
         with tracing.span("engine.admit.store", rid=r.rid):
             if store is not None:
                 reuse = r.reuse if r.reuse is not None else BS.RequestReuse()
                 # --- prefix tier: inject a hit before compute ---
-                key = self._prefix_full_key(r)
-                if key is not None:
-                    pblk = store.acquire(key)
+                pfx_key = self._prefix_full_key(r)
+                if pfx_key is not None:
+                    pblk = store.acquire(pfx_key)
                     if pblk is not None:
-                        held.append(key)
+                        held.append(pfx_key)
                         npfx = min(pblk.n_tokens, n)
-                        ck = np.array(ck, np.float32)
-                        cv = np.array(cv, np.float32)
                         have = have.copy()
-                        ck[:npfx] = pblk.host_k[:npfx]
-                        cv[:npfx] = pblk.host_v[:npfx]
                         have[:npfx] = True
                         pos_parts.append(np.arange(npfx))
                         slot_parts.append(pblk.slots[:npfx])
                     else:
-                        pending_prefix = key
+                        pending_prefix = pfx_key
                 # --- item tier: offline bytes exist now, so misses insert
                 # at admission (later arrivals hit them; this request keeps
                 # its own private rows — the bytes are identical either way)
@@ -873,9 +850,11 @@ class BatchEngine:
         mapped_mask = np.zeros(n, bool)
         mapped_mask[mapped_pos[mapped_pos < n].astype(np.int64)] = True
         with tracing.span("engine.admit.context", rid=r.rid):
+            prefix = self._prefix_rows(r, pblk) if pfx_key is not None else None
             cp = ENG.ChunkedPrefill(
-                self.params, self.cfg, plan, ck, cv, have, self.sel,
-                chunk_tokens=self.chunk_tokens, bucket=self.bucket,
+                self.params, self.cfg, plan, r.cached_k, r.cached_v, have,
+                self.sel, chunk_tokens=self.chunk_tokens, bucket=self.bucket,
+                prefix=prefix, device=self.device,
             )
         self.prefill_states[r.rid] = PrefillState(
             req=r,
@@ -1015,6 +994,9 @@ class BatchEngine:
             self.last_stats[rid] = rec.stats
         if rec.prefill is not None:
             st = rec.prefill
+            st.cp.params = self.params
+            if self.device is not None:
+                st.cp.to_device(self.device)
             if priv_old:
                 # privatized positions are no longer store-mapped: clear
                 # the mask so finalize writes (not remaps) them
@@ -1027,12 +1009,14 @@ class BatchEngine:
             self.prefill_states[rid] = st
         return counters
 
-    def _finalize_store(self, st: PrefillState, k_all, v_all, rec) -> np.ndarray:
+    def _finalize_store(self, st: PrefillState, kv: ENG.PrefillKV,
+                        rec: np.ndarray) -> np.ndarray:
         """Store bookkeeping for one finalizing request: insert the
-        fresh-byte tiers whose keys missed at admission, then un-share
-        every mapped position Eq. 3 selected for recomputation (its
-        fresh KV must land privately — writing through the shared slot
-        would corrupt the store's block).  -> remapped positions."""
+        fresh-byte tiers whose keys missed at admission (their rows are
+        pulled to the host, which the store keeps), then un-share every
+        mapped position Eq. 3 selected for recomputation (its fresh KV
+        must land privately — writing through the shared slot would
+        corrupt the store's block).  -> remapped positions."""
         store, r = self.store, st.req
         n = st.cp.n
         reuse = r.reuse if r.reuse is not None else BS.RequestReuse()
@@ -1042,8 +1026,7 @@ class BatchEngine:
             pblk = store.insert(
                 st.pending_prefix,
                 BS.PREFIX_TIER,
-                k_all[:npfx],
-                v_all[:npfx],
+                *kv.pull(np.arange(npfx)),
                 pinned=True,
                 defer_write=True,
             )
@@ -1052,8 +1035,7 @@ class BatchEngine:
                 held.append(st.pending_prefix)
         if st.pending_user is not None:
             ukey, u_pos = st.pending_user
-            ku = np.concatenate([k_all[u_pos, :1], r.cached_k[u_pos, 1:]], axis=1)
-            vu = np.concatenate([v_all[u_pos, :1], r.cached_v[u_pos, 1:]], axis=1)
+            ku, vu = self._user_rows(r, kv, u_pos)
             ublk = store.insert(
                 ukey,
                 BS.USER_TIER,
@@ -1076,9 +1058,9 @@ class BatchEngine:
 
         One bucketed batched dispatch (`engine.selective_layers_batch`
         — the same kernel the wave path uses, so chunked and monolithic
-        prefill decode bitwise-identical tokens), then one fused
-        deep-layer pool scatter for the whole batch; the layer-0 plane
-        already landed incrementally as chunks completed.
+        prefill decode bitwise-identical tokens), then each request's
+        deep-layer planes scattered from the device into its pages; the
+        layer-0 plane already landed incrementally as chunks completed.
         """
         with tracing.span("engine.finalize", rid=",".join(map(str, rids))):
             return self._finalize_prefill(rids)
@@ -1089,39 +1071,28 @@ class BatchEngine:
             self.params, self.cfg, [st.cp.sel_item() for st in states]
         )
         out: Dict[int, np.ndarray] = {}
-        entries_deep, entries_l0 = [], []
         for st, (logits, k_rest, v_rest) in zip(states, sel_out):
             r, cp = st.req, st.cp
-            n = cp.n
             stats = cp.stats
             self.last_stats[r.rid] = stats
             rec = stats.recompute_mask
-            with tracing.span("engine.finalize.assemble", rid=r.rid):
-                k_all = np.concatenate([cp.k0_full()[:, None], k_rest[:n]], axis=1)
-                v_all = np.concatenate([cp.v0_full()[:, None], v_rest[:n]], axis=1)
-            for positions, k0, v0 in st.l0_buf:  # lazy-mode chunk rows
-                entries_l0.append((r.rid, positions, k0, v0))
+            kv = ENG.PrefillKV(cp.k0, cp.v0, k_rest, v_rest)
+            l0_pos = list(st.l0_buf)  # lazy-mode chunk positions
             if self.store is not None:
                 with tracing.span("engine.finalize.store", rid=r.rid):
-                    remap = self._finalize_store(st, k_all, v_all, rec)
-                if len(remap):
                     # un-shared positions never got the incremental
                     # layer-0 write (they were mapped then) — their
                     # fresh plane lands with the finalize scatter
-                    entries_l0.append((r.rid, remap, k_all[remap, 0], v_all[remap, 0]))
-            with tracing.span("engine.finalize.assemble", rid=r.rid):
-                pos, kw, vw = self._selective_rows(r, stats, k_all, v_all)
-                keep = ~st.mapped_mask[pos]
-                entries_deep.append(
-                    (r.rid, pos[keep], kw[keep][:, 1:], vw[keep][:, 1:])
-                )
+                    l0_pos.append(self._finalize_store(st, kv, rec))
+            self._write_prefill(
+                r.rid, r.plan, rec, kv, st.mapped_mask,
+                np.concatenate(l0_pos) if l0_pos else np.zeros(0, np.int64),
+            )
             out[r.rid] = logits
             del self.prefill_states[r.rid]
         if self.store is not None:
             with tracing.span("engine.finalize.store"):
                 self.store.flush_writes()
-        self.pool.write_at_batch(entries_deep, deep=True)
-        self.pool.write_at_batch(entries_l0, layer=0)
         return out
 
     def step(
@@ -1161,7 +1132,7 @@ class BatchEngine:
         active = [rid for rid in prefill_rids if rid in self.prefill_states]
         packed = False
         finalize: List[int] = []
-        l0_entries: List[tuple] = []
+        l0_pos: Dict[int, List[np.ndarray]] = {}
 
         def try_finalize(rid) -> None:
             nonlocal left, packed
@@ -1193,12 +1164,12 @@ class BatchEngine:
                 if c > left:
                     rep.oversized = True
                 with tracing.span("engine.chunk", rid=rid):
-                    positions, k0, v0 = st.cp.run_chunk()
-                keep = ~st.mapped_mask[positions]
+                    positions = st.cp.run_chunk()
+                positions = positions[~st.mapped_mask[positions]]
                 if self.eager_kv_writes:
-                    l0_entries.append((rid, positions[keep], k0[keep], v0[keep]))
+                    l0_pos.setdefault(rid, []).append(positions)
                 else:
-                    st.l0_buf.append((positions[keep], k0[keep], v0[keep]))
+                    st.l0_buf.append(positions)
                 rep.charge_chunks += c
                 left -= c
                 packed = True
@@ -1210,7 +1181,12 @@ class BatchEngine:
                     rep.chunked.append(rid)
                 if st.cp.scan_done and rid not in finalize:
                     try_finalize(rid)
-        self.pool.write_at_batch(l0_entries, layer=0)
+        # one layer-0 write per request per tick: its planes hold every
+        # chunk the tick ran
+        for rid, parts in l0_pos.items():
+            cp = self.prefill_states[rid].cp
+            self.pool.write_rows(rid, np.concatenate(parts), cp.k0, cp.v0,
+                                 layer=0)
         if finalize:
             rep.finalized = self.finalize_prefill(finalize)
         return rep

@@ -22,17 +22,19 @@ shapes (and therefore decoded tokens) are identical either way.
 
 Insertion is block-granular: `write_plan` walks the assembly plan's
 contiguous spans (`core.assembly.plan_spans`) and fuses every cached
-block's run into one scatter; the selective engine merges the
-recomputed tokens' fresh KV host-side and inserts whole *batches* with
-`write_at_batch` — one arena update per batch instead of one per span.
+block's run into one scatter.  A selective prefill's rows never cross to
+the host: its merged K/V (cached rows with the recomputed rows written
+over them, `core.engine.PrefillKV`) stays on the device, and
+`write_rows` scatters the positions the host names straight out of those
+planes.  `write_at_batch` lands host rows (block-store bytes, migrated
+pages) the same way, one arena update per batch.
 
-Host-side writes go through one jitted, undonated scatter
-(`_jit_pool_scatter`: a full-arena copy per call, which is why fusing
-matters); the decode hot loop instead threads
-the arenas through the jitted decode step (`serving.batch_engine`) and
-installs the returned buffers, so the new tokens' KV lands in-step (the
-arenas are donated on TPU/GPU, making the update in-place; CPU lacks
-donation and copies).
+Every write goes through one jitted scatter (`_pool_scatter`) that
+donates the arenas where the backend implements donation (TPU/GPU), so
+the update is in place; CPU lacks donation, and there each write copies
+the arena.  The decode hot loop threads the arenas through the jitted
+decode step (`serving.batch_engine`) and installs the returned buffers,
+so the new tokens' KV lands in-step.
 """
 from __future__ import annotations
 
@@ -53,37 +55,51 @@ class PoolExhausted(RuntimeError):
     """No free pages left — caller should defer admission (backpressure)."""
 
 
-# Arena scatters are eager XLA ops compiled per *shape*: without
+# Arena scatters are XLA programs compiled per *shape*: without
 # padding, every distinct row count a batch composition produces
-# triggers a fresh ~100ms scatter compile — composition is wall-clock
-# sensitive, so steady-state serving would keep recompiling.  Padding
-# the fused scatters to row-count buckets caps that at O(log) compiles.
-# Pad rows target the scratch page (0, 0) with zero values: duplicates
-# in one scatter are only ever these identical zero writes, and the
-# scratch page is never read.
+# triggers a fresh compile — composition is wall-clock sensitive, so
+# steady-state serving would keep recompiling.  Padding the index
+# arrays to row-count buckets caps that at O(log) compiles.  Pad rows
+# target the scratch page (0, 0): duplicates in one scatter are only
+# ever these, and the scratch page is never read.
 WRITE_ROW_BUCKET = 512
 
 
-def _pad_scatter(pages, slots, k, v):
-    t = len(pages)
-    t_pad = -(-max(t, 1) // WRITE_ROW_BUCKET) * WRITE_ROW_BUCKET
-    if t_pad == t:
-        return pages, slots, k, v
-    extra = t_pad - t
-    pages = np.concatenate([pages, np.zeros(extra, pages.dtype)])
-    slots = np.concatenate([slots, np.zeros(extra, slots.dtype)])
-    zrow = np.zeros((extra,) + k.shape[1:], k.dtype)
-    return pages, slots, np.concatenate([k, zrow]), np.concatenate([v, zrow])
+def row_bucket(t: int, bucket: int = WRITE_ROW_BUCKET) -> int:
+    """`t` rows padded up to a multiple of `bucket` (at least one)."""
+    return -(-max(t, 1) // bucket) * bucket
 
 
-@functools.partial(jax.jit, static_argnums=(6,))
-def _jit_pool_scatter(arena_k, arena_v, pages, slots, k, v, planes):
-    """Rows `k`/`v` into (page, slot) of both arenas, in the layer planes
-    `planes`: "all", "deep" (1..L-1) or one layer index."""
+def _donates(arr) -> bool:
+    """Does the backend holding `arr` implement buffer donation?"""
+    return any(d.platform in ("tpu", "gpu") for d in arr.devices())
+
+
+def _pool_scatter(arena_k, arena_v, pages, slots, rows, k, v, planes):
+    """Rows `rows` of the sources `k`/`v` into (page, slot) of both
+    arenas, in the layer planes `planes`: "all", "deep" (1..L-1) or one
+    layer index."""
     with jax.named_scope("pool_scatter"):
         sel = {"all": slice(None), "deep": slice(1, None)}.get(planes, planes)
-        return (arena_k.at[pages, sel, :, slots].set(k),
-                arena_v.at[pages, sel, :, slots].set(v))
+        return (arena_k.at[pages, sel, :, slots].set(
+                    k[rows].astype(arena_k.dtype)),
+                arena_v.at[pages, sel, :, slots].set(
+                    v[rows].astype(arena_v.dtype)))
+
+
+@functools.lru_cache(maxsize=None)
+def _pool_scatter_jit(donate: bool):
+    """The jitted pool scatter, built on first use: with ``donate`` the
+    arenas (args 0, 1) are donated so the write is in place."""
+    return jax.jit(_pool_scatter, static_argnums=(7,),
+                   donate_argnums=(0, 1) if donate else ())
+
+
+@jax.jit
+@jax.named_scope("pool_gather")
+def _pool_gather(arena_k, arena_v, pages, slots):
+    """Token rows (t, L, Hkv, Dh) at (page, slot) of both arenas."""
+    return arena_k[pages, :, :, slots], arena_v[pages, :, :, slots]
 
 
 @dataclass(frozen=True)
@@ -187,6 +203,7 @@ class PagedKVPool:
             arena_v = jax.device_put(arena_v, sharding)
         self.arena_k = arena_k
         self.arena_v = arena_v
+        self.donate = _donates(arena_k)
         # page 0 is reserved as scratch: padded decode-batch rows write
         # their dummy token there, and padded slot-table entries point at
         # it (reads are masked by seq_lens).  It is never allocated.
@@ -376,58 +393,105 @@ class PagedKVPool:
     def write_at(self, rid: int, positions: np.ndarray,
                  k: np.ndarray, v: np.ndarray,
                  layer: Optional[int] = None) -> None:
-        """Scatter pre-RoPE (k, v) into logical slots.
+        """Scatter pre-RoPE host rows (k, v) into logical slots.
 
         k/v: (t, L, Hkv, Dh), or (t, Hkv, Dh) when `layer` selects a
-        single layer plane (e.g. the always-fresh layer-0 KV from the
-        selective engine).
+        single layer plane.
         """
         self.write_at_batch([(rid, positions, k, v)], layer=layer)
+
+    def _claim(self, rid: int, positions: np.ndarray
+               ) -> Tuple[np.ndarray, np.ndarray]:
+        """(pages, slots) of `rid`'s logical `positions`, with its
+        seq_len raised to cover them."""
+        pages, slots = self._phys(rid, positions)
+        self.seq_lens[rid] = max(self.seq_lens[rid], int(positions.max()) + 1)
+        return pages, slots
 
     def write_at_batch(self, entries: Sequence[tuple],
                        layer: Optional[int] = None,
                        deep: bool = False) -> None:
-        """Fused multi-request scatter: ONE arena update for any number
-        of requests' writes.
+        """Fused multi-request scatter of host rows: ONE arena update for
+        any number of requests' writes.
 
         entries: sequence of (rid, positions, k, v).  Positions must be
         unique within an entry (duplicate physical slots across a single
         scatter have undefined write order under XLA).  Entries with no
         positions are skipped (a fully store-mapped request writes
-        nothing).  Each arena update copies the arena (no donation),
-        so fusing a batch's insertions into one scatter is what makes
-        the batched prefill's pool insertion O(1) copies instead of
-        O(requests · spans).
-
-        ``deep`` writes only layer planes 1..L-1 from (t, L-1, ...) rows
-        — the chunk-resumable prefill's finalize path, whose layer-0
-        plane already landed incrementally as chunks completed.
+        nothing).  ``deep`` writes only layer planes 1..L-1 from
+        (t, L-1, ...) rows.
         """
         pages_all, slots_all, ks, vs = [], [], [], []
         for rid, positions, k, v in entries:
             positions = np.asarray(positions, np.int64)
             if len(positions) == 0:
                 continue
-            pages, slots = self._phys(rid, positions)
+            pages, slots = self._claim(rid, positions)
             pages_all.append(pages)
             slots_all.append(slots)
             ks.append(np.asarray(k))
             vs.append(np.asarray(v))
-            self.seq_lens[rid] = max(self.seq_lens[rid],
-                                     int(positions.max()) + 1)
         if not pages_all:
             return
         with tracing.span("pool.write"):
-            self._scatter(np.concatenate(pages_all), np.concatenate(slots_all),
-                          np.concatenate(ks), np.concatenate(vs),
-                          "deep" if deep else ("all" if layer is None else layer))
+            self._scatter_host(
+                np.concatenate(pages_all), np.concatenate(slots_all),
+                np.concatenate(ks), np.concatenate(vs),
+                "deep" if deep else ("all" if layer is None else layer))
 
-    def _scatter(self, pages, slots, k, v, planes) -> None:
-        """Pad to the row bucket, upload, and dispatch the arena scatter."""
-        pages, slots, k, v = _pad_scatter(pages, slots, k, v)
-        tracing.count("h2d_bytes", tracing.nbytes(pages, slots, k, v))
-        self.arena_k, self.arena_v = _jit_pool_scatter(
-            self.arena_k, self.arena_v, pages, slots, k, v, planes)
+    def write_rows(self, rid: int, positions: np.ndarray, k, v,
+                   layer: Optional[int] = None, deep: bool = False,
+                   pad_to: int = WRITE_ROW_BUCKET) -> None:
+        """Scatter device planes indexed by prompt position into one
+        request's logical slots: row p of `k`/`v` lands at position p,
+        for each p in `positions`, and never crosses to the host.
+
+        k/v: (S, L, Hkv, Dh) planes; (S, L-1, Hkv, Dh) with ``deep``;
+        (S, Hkv, Dh) with `layer`.  The index arrays are padded to a
+        multiple of `pad_to` rows, so the program is keyed by the planes'
+        shape and that bucket only.
+        """
+        positions = np.asarray(positions, np.int64)
+        if len(positions) == 0:
+            return
+        with tracing.span("pool.write"):
+            pages, slots = self._claim(rid, positions)
+            self._scatter(pages, slots, positions, k, v,
+                          "deep" if deep else ("all" if layer is None else layer),
+                          pad_to)
+
+    def _scatter(self, pages, slots, rows, k, v, planes,
+                 pad_to: int = WRITE_ROW_BUCKET) -> None:
+        """Pad the index arrays, upload them, dispatch the arena scatter."""
+        t = len(pages)
+        idx = np.zeros((3, row_bucket(t, pad_to)), np.int32)
+        idx[0, :t], idx[1, :t], idx[2, :t] = pages, slots, rows
+        tracing.count("h2d_bytes", idx.nbytes)
+        self.arena_k, self.arena_v = _pool_scatter_jit(self.donate)(
+            self.arena_k, self.arena_v, idx[0], idx[1], idx[2], k, v, planes)
+
+    def _scatter_host(self, pages, slots, k, v, planes) -> None:
+        """Upload host rows padded to the row bucket and scatter them."""
+        t = len(pages)
+        t_pad = row_bucket(t)
+        zrow = np.zeros((t_pad - t,) + k.shape[1:], k.dtype)
+        k, v = np.concatenate([k, zrow]), np.concatenate([v, zrow])
+        tracing.count("h2d_bytes", tracing.nbytes(k, v))
+        self._scatter(pages, slots, np.arange(t), jnp.asarray(k),
+                      jnp.asarray(v), planes)
+
+    def gather_slots(self, slot_ids: np.ndarray, n_rows: int
+                     ) -> Tuple[jax.Array, jax.Array]:
+        """Device rows (t_pad, L, Hkv, Dh) at physical `slot_ids`, padded
+        with scratch rows to the row bucket of `n_rows` (>= the ids
+        given), so the program does not depend on how many rows a caller
+        found.  Nothing crosses to the host but the slot ids."""
+        slot_ids = np.asarray(slot_ids, np.int64)
+        idx = np.zeros((2, row_bucket(max(n_rows, len(slot_ids)))), np.int32)
+        idx[0, :len(slot_ids)] = slot_ids // self.page_size
+        idx[1, :len(slot_ids)] = slot_ids % self.page_size
+        tracing.count("h2d_bytes", idx.nbytes)
+        return _pool_gather(self.arena_k, self.arena_v, idx[0], idx[1])
 
     def write_slots(self, slot_ids: np.ndarray,
                     k: np.ndarray, v: np.ndarray) -> None:
@@ -436,11 +500,9 @@ class PagedKVPool:
         self.write_slots_batch([(slot_ids, k, v)])
 
     def write_slots_batch(self, entries: Sequence[tuple]) -> None:
-        """Fused multi-block physical-slot scatter: ONE arena update for
-        any number of (slot_ids, k, v) writes.  Arena updates are full
-        copies, so the store flushes a whole prefill batch's
-        block insertions through here instead of paying one copy per
-        block."""
+        """Fused multi-block physical-slot scatter of host rows: ONE arena
+        update for any number of (slot_ids, k, v) writes — the store
+        flushes a whole prefill batch's block insertions through here."""
         if not entries:
             return
         with tracing.span("pool.write"):
@@ -448,8 +510,8 @@ class PagedKVPool:
                 [np.asarray(s, np.int64) for s, _, _ in entries])
             k = np.concatenate([np.asarray(k) for _, k, _ in entries])
             v = np.concatenate([np.asarray(v) for _, _, v in entries])
-            self._scatter(slot_ids // self.page_size, slot_ids % self.page_size,
-                          k, v, "all")
+            self._scatter_host(slot_ids // self.page_size,
+                               slot_ids % self.page_size, k, v, "all")
 
     def write_prompt(self, rid: int, k: np.ndarray, v: np.ndarray) -> None:
         """Insert a full prompt cache (n, L, Hkv, Dh) starting at slot 0."""

@@ -82,10 +82,101 @@ def test_chunked_prefill_matches_monolithic(tiny_system, chunk):
             system.params, system.cfg, [cp.sel_item()])
         assert np.array_equal(stats_m.recompute_mask, cp.stats.recompute_mask)
         assert np.array_equal(logits_m, logits_c)
-        k_c = np.concatenate([cp.k0_full()[:, None], k_rest[:cp.n]], axis=1)
-        v_c = np.concatenate([cp.v0_full()[:, None], v_rest[:cp.n]], axis=1)
+        n = cp.n
+        k_c = np.concatenate([np.asarray(cp.k0)[:n, None],
+                              np.asarray(k_rest)[:n]], axis=1)
+        v_c = np.concatenate([np.asarray(cp.v0)[:n, None],
+                              np.asarray(v_rest)[:n]], axis=1)
         assert np.array_equal(k_m, k_c)
         assert np.array_equal(v_m, v_c)
+
+
+# ------------------------------------- finalize lands in the arena
+def _host_merge(plan, rec, cached, fresh, mapped):
+    """Reference rows the host used to scatter: cached span values first,
+    then the recomputed tokens' fresh rows over them (all L planes),
+    at the positions not mapped at a store slot -> {position: row}."""
+    from repro.core.assembly import RECOMPUTE, plan_spans
+
+    write = rec.copy()
+    for sp in plan_spans(plan):
+        if sp.source != RECOMPUTE:
+            write[sp.start:sp.end] = True
+    rows = np.array(cached, np.float32)
+    rows[rec] = fresh[rec]
+    return {p: rows[p] for p in np.where(write & ~mapped)[0]}
+
+
+@pytest.mark.parametrize("eager_kv_writes", [False, True])
+@pytest.mark.parametrize("kv_reuse", [False, True])
+def test_finalize_lands_the_host_merge_in_the_arena(tiny_system, heavy_workload,
+                                                    kv_reuse, eager_kv_writes):
+    """After a chunked finalize, the arena row at every position of the
+    request equals, bitwise, what the host merge of the same
+    `ChunkedPrefill` outputs gives: layer 0 fresh, layers 1..L-1 the
+    cached span values with the recomputed rows over them, where the
+    position is private; the store block's bytes where it stays mapped.
+    With reuse, a repeat of the first prompt hits the prefix tier, and
+    Eq. 3 recomputes positions mapped at admission (remapped private).
+    Layer 0 lands chunk by chunk (``eager_kv_writes``, the TPU default)
+    or with the finalize scatter (the CPU default)."""
+    from repro.serving.batch_engine import BatchRequest
+
+    system, *_ = tiny_system
+    _, _, plans, reuse = heavy_workload
+    cfg = system.cfg
+    pool = pool_for(cfg, n_pages=1024)
+    store = SharedBlockStore(pool) if kv_reuse else None
+    eng = BatchEngine(system.params, cfg, pool=pool, store=store,
+                      chunk_tokens=64, eager_kv_writes=eager_kv_writes)
+    first = sorted(plans)[0]
+    runs = [(rid, rid) for rid in sorted(plans)] + [(100, first)]
+    hits = remaps = 0
+    for rid, src in runs:
+        plan, ck, cv, have = plans[src]
+        eng.begin_prefill(BatchRequest(
+            rid=rid, tokens=plan.tokens, plan=plan, cached_k=ck, cached_v=cv,
+            have=have, n_reserve=2, reuse=reuse[src] if kv_reuse else None))
+        st = eng.prefill_states[rid]
+        mapped_at_admit = st.mapped_mask.copy()
+        while not st.cp.scan_done:
+            eng.step(64, [], [], [rid])  # one chunk a tick, no finalize
+        cp = st.cp
+        (logits, k_rest, v_rest), = ENG.selective_layers_batch(
+            system.params, cfg, [cp.sel_item()])
+        rec = cp.stats.recompute_mask
+        rep = eng.step(10_000, [], [], [rid])
+        assert np.array_equal(rep.finalized[rid], logits)
+
+        n = plan.n
+        k0, v0 = np.asarray(cp.k0)[:n], np.asarray(cp.v0)[:n]
+        k_all = np.concatenate([k0[:, None], np.asarray(k_rest)[:n]], axis=1)
+        v_all = np.concatenate([v0[:, None], np.asarray(v_rest)[:n]], axis=1)
+        mapped = st.mapped_mask
+        hits += int(have[0] != mapped_at_admit[0])  # the instruction mapped
+        remaps += int((mapped_at_admit & rec).sum())
+        ref_k = _host_merge(plan, rec, ck, k_all, mapped)
+        ref_v = _host_merge(plan, rec, cv, v_all, mapped)
+        got_k, got_v = pool.gather(rid)
+        table = pool.slot_tables[rid]
+        blocks = {} if store is None else {
+            int(sl): (blk.host_k[i], blk.host_v[i])
+            for blk in store.blocks.values() for i, sl in enumerate(blk.slots)}
+        assert pool.seq_len(rid) == n
+        for p in range(n):
+            if mapped[p]:
+                bk, bv = blocks[int(table[p])]
+                assert np.array_equal(got_k[p], bk) and np.array_equal(got_v[p], bv)
+                continue
+            assert np.array_equal(got_k[p, 0], k0[p]), p
+            assert np.array_equal(got_v[p, 0], v0[p]), p
+            assert np.array_equal(got_k[p, 1:], ref_k[p][1:]), p
+            assert np.array_equal(got_v[p, 1:], ref_v[p][1:]), p
+    if kv_reuse:
+        assert hits >= 1 and remaps >= 1
+    for rid, _ in runs:
+        eng.release(rid)
+    check_partition(pool, store)
 
 
 # ------------------------------------------ serving-loop token parity

@@ -7,9 +7,10 @@ the CPU:
   what a run without it decodes, and the trace holds ``rcllm.*`` host
   events carrying the request's ``rid``;
 * each `TickRecord`'s phase seconds fit inside the tick's wall seconds;
-* a tick that only finalizes counts exactly the bytes the selective
-  layers pull back: the merged K/V of layers 1..L-1 in float32 for the
-  padded batch, plus the logits;
+* a tick that only finalizes pulls the logits plus only the rows of a
+  block-store insert it owes, and a whole chunked prefill moves less
+  than one float32 K/V plane of its prompt either way: the K/V stays on
+  the device;
 * the first tick of a shape records a compile, a repeat records none;
 * queue waits run on the server's wall clock: never negative, and
   longer by the time the server was stalled before the tick.
@@ -26,17 +27,24 @@ import pytest
 from repro.serving import api as API
 from repro.serving import tracing
 from repro.serving import workload as WL
+from repro.serving.kv_pool import row_bucket
 from repro.serving.server import AsyncSessionServer
 
 
 @pytest.fixture(scope="module")
-def tiny():
+def tiny_trace():
     from repro.core.rcllm import make_tiny_system
 
     system, pool_rv, prof, _ = make_tiny_system(
         n_items=60, n_requests_hist=30, k_instances=1, n_layers=2, d_model=32)
     trace = WL.heavy_tail_trace(system.catalog, pool_rv, prof, 2, qps=8.0,
                                 n_users=2, seed=7)
+    return system, trace
+
+
+@pytest.fixture(scope="module")
+def tiny(tiny_trace):
+    system, trace = tiny_trace
     pend, plans = WL.rcllm_workload(system, trace, decode_steps=3)
     return system, pend[0], plans[pend[0].rid]
 
@@ -166,22 +174,85 @@ def test_phase_seconds_fit_inside_the_tick(served):
     assert sum(t.n_finalized for t in ticks) == 2
 
 
-def test_finalize_tick_counts_the_bytes_it_pulls(tiny, served):
-    system, p, _ = tiny
+def test_finalize_tick_counts_the_bytes_it_pulls(tiny_trace, tiny, served):
+    system, p, plan = tiny
     server, _ = served
     cfg = system.cfg
+    row = cfg.n_layers * cfg.n_kv_heads * cfg.resolved_head_dim * 4
+    plane = p.n_tokens * row  # one float32 K (or V) plane of the prompt
+    logits = cfg.vocab_size * jax.numpy.dtype(cfg.dtype).itemsize
     alone = [t for t in server.worker.ticks
              if t.finalize_tokens and not t.chunk_tokens and not t.decode_tokens]
     assert len(alone) == 2
-    n_pad = -(-p.n_tokens // 64) * 64
-    B = 1
-    kv = 2 * B * n_pad * (cfg.n_layers - 1) * cfg.n_kv_heads * cfg.resolved_head_dim * 4
-    logits = B * cfg.vocab_size * jax.numpy.dtype(cfg.dtype).itemsize
     for t in alone:
+        # no store here: the K/V stays on the device, the logits come back
         assert t.n_finalized == 1
-        assert t.d2h_bytes == kv + logits
-        # the stacked cached K/V of every layer goes up, float32
-        assert t.h2d_bytes >= kv * cfg.n_layers // (cfg.n_layers - 1)
+        assert t.d2h_bytes == logits
+        assert t.h2d_bytes < plane
+    # the client's cached rows go up once, padded to the row bucket;
+    # beyond them a whole prefill moves less than one plane either way
+    cached = 2 * row_bucket(int(plan[3].sum())) * row
+    for rid in (1, 2):
+        prefill = [t for t in _ticks_of(server, rid)
+                   if t.chunk_tokens or t.finalize_tokens]
+        assert sum(t.h2d_bytes for t in prefill) - cached < plane
+        assert sum(t.d2h_bytes for t in prefill) < plane
+
+    # with the block store, the first finalize owes the tier inserts that
+    # missed and pulls exactly their rows (padded to the row bucket)
+    # beside its logits; a repeat hits every block and pulls the logits
+    # alone.  `cold` is the benchmark's client: nothing cached, so only
+    # the prefix tier (the instruction) is shared
+    from repro.serving.block_store import RequestReuse, user_reuse_positions
+
+    trace = tiny_trace[1]
+    _, plans = WL.rcllm_workload(system, trace)
+    reuse = WL.rcllm_reuse_info(system, trace, plans)[p.rid]
+    npfx = min(reuse.prefix_len, plan[0].n)
+    u_pos = user_reuse_positions(plan[0], plan[3], reuse.prefix_end)
+    assert npfx > 0 and len(u_pos) > 0
+    prefix_rows = 2 * row_bucket(npfx) * row  # every layer
+    user_rows = 2 * row_bucket(len(u_pos)) * row // cfg.n_layers  # layer 0
+    cold = RequestReuse(prefix_key=reuse.prefix_key, prefix_len=reuse.prefix_len)
+    zeros = np.zeros_like(plan[1])
+    for have, ck, cv, ru, owed in (
+            (plan[3], plan[1], plan[2], reuse, prefix_rows + user_rows),
+            (np.zeros_like(plan[3]), zeros, zeros, cold, prefix_rows)):
+        pulled, up, down = _store_prefills(system, plan[0], ck, cv, have, ru)
+        assert pulled == [logits + owed, logits]
+        # the repeat's prefix rows are gathered from the arena on the
+        # device: nothing but its own cached rows goes up
+        n_have = int(have.sum())
+        assert up - (2 * row_bucket(n_have) * row if n_have else 0) < plane
+        assert down < plane
+
+
+def _store_prefills(system, plan, ck, cv, have, reuse):
+    """Two chunked prefills of one prompt through an engine with a block
+    store, a 64-token tick budget.  -> (bytes each finalize tick pulled,
+    bytes the repeat moved up and down, admission included)."""
+    from repro.serving.batch_engine import BatchEngine, BatchRequest
+    from repro.serving.block_store import SharedBlockStore
+    from repro.serving.kv_pool import pool_for
+
+    pool = pool_for(system.cfg, n_pages=256)
+    eng = BatchEngine(system.params, system.cfg, pool=pool,
+                      store=SharedBlockStore(pool), chunk_tokens=64)
+    pulled = []
+    for rid in (1, 2):
+        with tracing.Tick() as t:
+            eng.begin_prefill(BatchRequest(
+                rid=rid, tokens=plan.tokens, plan=plan, cached_k=ck,
+                cached_v=cv, have=have, reuse=reuse))
+        up, down = t.counts["h2d_bytes"], t.counts["d2h_bytes"]
+        while True:
+            with tracing.Tick() as t:
+                rep = eng.step(64, [], [], [rid])
+            up, down = up + t.counts["h2d_bytes"], down + t.counts["d2h_bytes"]
+            if rep.finalized:
+                pulled.append(t.counts["d2h_bytes"])
+                break
+    return pulled, up, down
 
 
 def test_compile_on_the_first_tick_of_a_shape_only(served):
