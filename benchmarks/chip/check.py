@@ -2,9 +2,10 @@
 
 After the window has closed, a sample of the finished window requests
 (the longest prompt and others drawn from the seed) is replayed through
-the configuration's plain float32 reference: each prompt followed by
-its served tokens, one pass.  For every served token the reference
-gives its logits at that position; the number compared is the widest
+the configuration's plain float32 reference (``references/<name>.py``,
+found by `archs.reference`): each prompt followed by its served tokens,
+one pass.  For every served token the reference gives its logits at
+that position; the number compared is the widest
 gap by which a served token's logit lies below the reference's best
 (`max_gap`, in logits).  Served tokens are greedy, so a sound program
 reads a gap of rounding size and a wrong mask, position, layer or cache
@@ -20,22 +21,11 @@ limit's upper reading.
 """
 from __future__ import annotations
 
-import importlib.util
-import os
 from typing import Dict, List, Sequence
 
 import numpy as np
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-
-
-def reference(cfg: dict):
-    """The configuration's plain reference module (``references/<name>.py``)."""
-    path = os.path.join(HERE, "references", f"{cfg['reference']}.py")
-    spec = importlib.util.spec_from_file_location(f"reference_{cfg['reference']}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+import archs
 
 
 def sequences(prompts: Sequence[np.ndarray], served: Sequence[List[int]]):
@@ -63,7 +53,7 @@ def readings(cfg: dict, seed: int, prompts, served, controls=()) -> Dict[str, fl
     n = min(len(s) for s in served)
     served = [s[:n] for s in served]
     seqs = sequences(prompts, served)
-    mod = reference(cfg)
+    mod = archs.reference(cfg)
     ref = mod.logits(cfg, seed, seqs, n)
     g = gaps(ref, served)
     out = {"max_gap": float(g.max()), "tokens_compared": int(g.size),

@@ -1,4 +1,4 @@
-"""Operations the served algorithm needs, counted from the widths.
+"""Operations the served algorithm needs, counted by the architecture.
 
 RcLLM's prefill (Eq. 3 selective recompute) runs layer 0 over every
 prompt token and layers 1..L-1 only over the rows it recomputes; the
@@ -14,36 +14,24 @@ rest reuse cached KV.  Per request that is:
 
 A multiply-add is two operations.  Norms, RoPE, softmax and the Eq. 3
 scoring are left out: they are elementwise and small beside the above.
+
+What a row costs in a layer is the architecture's: each architecture
+module (`archs`) counts ``prefill(cfg, n, n_recomputed,
+recomputed_ctx)`` and ``decode(cfg, position)`` from its own widths.
+``flops.<count>(cfg, ...)`` is the configuration's architecture's
+``<count>(cfg, ...)``.
 """
 from __future__ import annotations
 
-
-def linear_per_row(cfg: dict) -> int:
-    """Projection and MLP operations for one row through one layer."""
-    D, H, Hkv, Dh, F = (cfg["d_model"], cfg["n_heads"], cfg["n_kv_heads"],
-                        cfg["head_dim"], cfg["d_ff"])
-    n_mlp = 3 if cfg["mlp_type"] == "swiglu" else 2
-    return 2 * (2 * D * H * Dh + 2 * D * Hkv * Dh + n_mlp * D * F)
+import archs
 
 
-def attention(cfg: dict, keys: int) -> int:
-    """QK^T and PV for query rows attending to `keys` keys in total."""
-    return 4 * cfg["n_heads"] * cfg["head_dim"] * keys
+def __getattr__(name: str):
+    if name.startswith("__"):
+        raise AttributeError(name)
 
+    def count(cfg: dict, *args) -> int:
+        return getattr(archs.of(cfg), name)(cfg, *args)
 
-def head(cfg: dict) -> int:
-    return 2 * cfg["d_model"] * cfg["vocab_size"]
-
-
-def prefill(cfg: dict, n: int, n_recomputed: int, recomputed_ctx: int) -> int:
-    """One prompt of `n` tokens whose deep layers recompute `n_recomputed`
-    rows attending to `recomputed_ctx` keys in all (row p sees p + 1)."""
-    lin = linear_per_row(cfg)
-    layer0 = n * lin + attention(cfg, n * (n + 1) // 2)
-    deep = (cfg["n_layers"] - 1) * (n_recomputed * lin + attention(cfg, recomputed_ctx))
-    return layer0 + deep + head(cfg)
-
-
-def decode(cfg: dict, position: int) -> int:
-    """One decoded token written at `position` (it sees position + 1 keys)."""
-    return cfg["n_layers"] * (linear_per_row(cfg) + attention(cfg, position + 1)) + head(cfg)
+    count.__name__ = name
+    return count

@@ -1,6 +1,7 @@
 """Plain reference of a dense decoder-only transformer, in float32.
 
-The architecture both configurations share: token embedding; per layer a
+The ``dense_decoder`` architecture (`archs/dense_decoder.py` lays out its
+parameters and counts its operations): token embedding; per layer a
 pre-RMSNorm grouped-query attention block with rotary position embedding
 (half-split rotation, base ``rope_theta``) and a pre-RMSNorm MLP
 (``swiglu``: silu(x Wg) * (x Wu) Wd; ``relu2``: relu(x Wu)^2 Wd); a final

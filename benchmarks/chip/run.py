@@ -5,12 +5,18 @@
 
 Runs only where JAX's devices are TPUs, at least as many as the cell
 asks for, and the device kind is in ``peaks.json``; anywhere else it
-says why on stderr and exits 2 without a result.  Everything the run
-needs is found by name: the cell in ``BENCHMARK.json`` at the checkout's
-root, its configuration in ``configs/<config>.json``, its traffic mix in
-``traffic/<mix>.json``, its output limits in ``limits/<cell>.json`` and
-each metric's reader in ``metrics/<metric>.py``.  A later cell, mix or
-metric is a new file and a new entry, never an edit here.
+says why on stderr and exits 2 without a result.  So does a cell whose
+traffic draws token ids the configuration's vocabulary lacks.
+Everything the run needs is found by name: the cell in
+``BENCHMARK.json`` at the checkout's root, its configuration in
+``configs/<config>.json``, the configuration's architecture
+(``archs/<reference>.py``) and plain reference
+(``references/<reference>.py``), its traffic mix in
+``traffic/<mix>.json``, its output limits in ``limits/<cell>.json``
+and each metric's reader in ``metrics/<metric>.py``.  A later cell,
+mix, metric or architecture is new files and new entries, never an
+edit here: what a model's shape makes of its keys, its parameter tree
+and its operation counts is its architecture module's (`archs`).
 
 One run: weights from ``--seed`` on the device, the program's offline
 caches, the one-chip wall-clock session server, a warm-up over the
@@ -49,11 +55,17 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 
 import numpy as np  # noqa: E402
 
+import archs  # noqa: E402
+
 CACHE_DIR = os.path.join(ROOT, ".jax_cache")
 SAMPLE_REQUESTS = 32  # requests whose served tokens the reference checks
 
 
 class NoChip(RuntimeError):
+    pass
+
+
+class BadCell(RuntimeError):
     pass
 
 
@@ -83,18 +95,38 @@ def load_reader(name: str):
     return mod.read
 
 
+def _flat(c: dict, prefix: str = "") -> dict:
+    """Nested groups as dotted keys, lists as tuples: flat and hashable."""
+    out = {}
+    for k, v in c.items():
+        if isinstance(v, dict):
+            out.update(_flat(v, f"{prefix}{k}."))
+        else:
+            out[prefix + k] = tuple(v) if isinstance(v, list) else v
+    return out
+
+
 def load_config(name: str, root: str = HERE) -> dict:
-    """A configuration file, mapped onto the harness's keys."""
+    """``<root>/configs/<name>.json``, mapped onto the harness's keys by its
+    architecture (``harness_keys``), with every other key of the file kept
+    beside them (nested ones as ``group.key``).  The architecture and the
+    reference are looked for under `root` first, then in the harness."""
     c = load_json(root, "configs", f"{name}.json")
-    return {
-        "name": c["name"], "reference": c["reference"],
-        "n_layers": c["num_hidden_layers"], "d_model": c["hidden_size"],
-        "n_heads": c["num_attention_heads"], "n_kv_heads": c["num_key_value_heads"],
-        "head_dim": c["head_dim"], "d_ff": c["intermediate_size"],
-        "vocab_size": c["vocab_size"], "mlp_type": c["mlp"],
-        "rope_theta": float(c["rope_theta"]), "norm_eps": c["rms_norm_eps"],
-        "dtype": c["torch_dtype"], "n_pages": c["serving"]["n_pages"],
-    }
+    arch = c.get("reference", archs.DEFAULT)
+    root = os.path.abspath(root)
+    return {**_flat(c), **archs.find("archs", arch, root).harness_keys(c),
+            "reference": arch, "root": root}
+
+
+def refuse_unknown_tokens(cfg: dict, mix: dict, mix_name: str) -> None:
+    """`BadCell` where the mix draws token ids at or above the vocabulary:
+    the embedding's gather would clamp them without a word."""
+    import traffic as TR
+
+    top = TR.token_id_bound(mix) - 1
+    if top >= cfg["vocab_size"]:
+        raise BadCell(f"traffic {mix_name!r} draws token ids up to {top}, at or above "
+                      f"vocab_size {cfg['vocab_size']} of configuration {cfg['name']!r}")
 
 
 class RunView:
@@ -102,6 +134,7 @@ class RunView:
 
     def __init__(self, cfg, served, seconds, setup_s, peak, trace):
         self.cfg = cfg
+        self.arch = archs.of(cfg)  # its operation counts, for the readers
         self.served = served
         self.reqs = served.reqs
         self.ticks = served.ticks
@@ -130,11 +163,9 @@ class RunView:
                    if r.finished and r.done_t is not None and s.t0 <= r.done_t <= s.t1)
 
     def flops_in_window(self) -> float:
-        """Operations done in the window: each request's prefill at its
-        first token, each decoded token at its publish time."""
-        import flops as F
-
-        s, cfg, total = self.served, self.cfg, 0
+        """Operations done in the window (`flops.py`): each request's
+        prefill at its first token, each decoded token at its publish time."""
+        s, cfg, arch, total = self.served, self.cfg, self.arch, 0
         for r in self.reqs:
             st = self.stats.get(r.idx)
             for i, t in enumerate(r.token_t):
@@ -142,11 +173,18 @@ class RunView:
                     continue
                 if i == 0:
                     if st is not None:
-                        total += F.prefill(cfg, st["n_tokens"], st["n_recomputed"],
-                                           st["recomputed_ctx"])
+                        total += arch.prefill(cfg, st["n_tokens"], st["n_recomputed"],
+                                              st["recomputed_ctx"])
                 else:
-                    total += F.decode(cfg, r.n_prompt + i - 1)
+                    total += arch.decode(cfg, r.n_prompt + i - 1)
         return float(total)
+
+
+def arena_bytes(pool) -> int:
+    """Bytes of the KV pool's arena arrays (``arena*``), however many
+    planes it keeps."""
+    return sum(int(a.nbytes) for k, a in vars(pool).items()
+               if k.startswith("arena") and hasattr(a, "nbytes"))
 
 
 def device_record(devices) -> dict:
@@ -233,7 +271,7 @@ def run_cell(cell: str, cfg: dict, mix: dict, limits: dict, metrics: list, *, se
     stats = [d.memory_stats() or {} for d in devices]
     mem_peak = max(int(s.get("peak_bytes_in_use", 0)) for s in stats)
     mem_limit = min(int(s.get("bytes_limit", 0)) for s in stats)
-    arena_bytes = int(system.engine.pool.arena_k.nbytes + system.engine.pool.arena_v.nbytes)
+    arena = arena_bytes(system.engine.pool)
     reduced = None
     if trace:
         dev_events, host, programs = xplane.read_events(xplane.find_xplane(trace_dir))
@@ -281,7 +319,7 @@ def run_cell(cell: str, cfg: dict, mix: dict, limits: dict, metrics: list, *, se
         "offered_rate_per_s": tr.rate_per_s, "requests_due": len(served.reqs),
         "requests_finished": len(finished), "completed_in_window": view.completed_in_window(),
         "warmup_requests": len(serve.warmup_plan(tr.warmup, tr.max_tokens)), "compiles_in_window": served.compiles,
-        "setup_s": setup_s, "setup_phases_s": phases, "param_bytes": param_bytes, "arena_bytes": arena_bytes,
+        "setup_s": setup_s, "setup_phases_s": phases, "param_bytes": param_bytes, "arena_bytes": arena,
         "memory_peak_bytes": mem_peak, "memory_limit_bytes": mem_limit,
         "ttft_max_ms": max(lat) * 1e3 if lat else None,
         # every latency and rate, whichever the cell reports
@@ -313,17 +351,18 @@ def main(argv=None) -> int:
     spec = load_json(ROOT, "BENCHMARK.json")
     cell = cell_of(spec, args.workload)
     peaks = load_json(HERE, "peaks.json")["devices"]
+    import traffic as TR
+
+    cfg, mix = load_config(cell["config"]), TR.load_mix(cell["traffic"])
     try:
+        refuse_unknown_tokens(cfg, mix, cell["traffic"])
         devices = require_devices(int(cell["chips"]), peaks)
-    except NoChip as e:
+    except (BadCell, NoChip) as e:
         print(f"chip benchmark: {e}; nothing was run", file=sys.stderr)
         return 2
     use_cache()
-    import traffic as TR
-
     result, info, compared = run_cell(
-        cell["name"], load_config(cell["config"]), TR.load_mix(cell["traffic"]),
-        load_json(HERE, "limits", f"{cell['name']}.json"),
+        cell["name"], cfg, mix, load_json(HERE, "limits", f"{cell['name']}.json"),
         metrics_for(spec, cell["name"], bool(args.trace)),
         seed=args.seed, seconds=args.seconds, trace=bool(args.trace), devices=devices,
         peak=peaks[devices[0].device_kind])

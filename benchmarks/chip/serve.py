@@ -47,17 +47,6 @@ WARMUP_RID = 1_000_000  # warm-up requests' rids start here
 WINDOW_SPAN = "bench.window"  # host span over the measured window (traced runs)
 
 
-def lm_config(cfg: dict):
-    from repro.configs.base import LMConfig
-
-    return LMConfig(
-        name=cfg["name"], n_layers=cfg["n_layers"], d_model=cfg["d_model"],
-        n_heads=cfg["n_heads"], n_kv_heads=cfg["n_kv_heads"],
-        head_dim=cfg["head_dim"], d_ff=cfg["d_ff"], vocab_size=cfg["vocab_size"],
-        mlp_type=cfg["mlp_type"], rope_theta=cfg["rope_theta"],
-        norm_eps=cfg["norm_eps"], dtype=cfg["dtype"], remat=False)
-
-
 def serve_config(cfg: dict):
     from repro.serving import api as API
 
@@ -109,10 +98,11 @@ class System:
     and only the session store's shared instruction prefix is reused."""
 
     def __init__(self, cfg: dict, params, traffic, structure_seed: int):
+        import archs
         from repro.core.rcllm import RcLLMSystem
         from repro.serving import api as API
 
-        self.lm = lm_config(cfg)
+        self.lm = archs.of(cfg).lm_config(cfg)
         if traffic.offline:
             self.system = RcLLMSystem.build(
                 params, self.lm, traffic.catalog, traffic.corpus,

@@ -38,6 +38,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 BOS, ITEM_SEP, REVIEW_SEP, RANK_QUERY = 1, 2, 3, 4
 SLOT_BASE = 8  # slot tokens 8..8+64 name "candidate #k"
 N_SPECIAL = SLOT_BASE + 64
+INSTRUCTION_WORDS = 200  # the instruction's ids: N_SPECIAL + [0, 200)
 
 
 def load_mix(name: str, root: str = HERE) -> dict:
@@ -54,6 +55,17 @@ def load_mix(name: str, root: str = HERE) -> dict:
         else:
             out[key] = val
     return out
+
+
+def token_id_bound(mix: dict) -> int:
+    """One past the largest token id `make_traffic` can draw from `mix`:
+    special and instruction ids, items, reviews, and a new user's reviews
+    where the mix has them (``users.unique``)."""
+    v = mix["vocab"]
+    regions = [N_SPECIAL + INSTRUCTION_WORDS, v["item_hi"], v["review_hi"]]
+    if mix["users"].get("unique"):
+        regions.append(v["cold_review_hi"])
+    return max(regions)
 
 
 def rng_for(*parts: int) -> np.random.Generator:
@@ -148,7 +160,8 @@ def sample_candidates(catalog: Catalog, n: int, rng: np.random.Generator,
 
 
 def make_instruction(n_tokens: int, rng: np.random.Generator) -> np.ndarray:
-    return np.concatenate([[BOS], N_SPECIAL + rng.integers(0, 200, n_tokens - 1)]).astype(np.int32)
+    words = N_SPECIAL + rng.integers(0, INSTRUCTION_WORDS, n_tokens - 1)
+    return np.concatenate([[BOS], words]).astype(np.int32)
 
 
 @dataclass

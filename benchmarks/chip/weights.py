@@ -5,10 +5,13 @@ the whole tree on the device in one jitted call, in the served dtype,
 and the plain reference (`references/`) rebuilds any single leaf from
 the same seed with the same functions, so the two sides share no array.
 
-Every leaf is drawn from its own key, ``fold_in(base, leaf_id)``, and the
-two vocabulary-sized matrices are drawn in row blocks of `VOCAB_BLOCK`,
-each from its own key: the reference can then make the embedding rows
-and the LM head one block at a time, in memory it can afford.
+Every leaf is drawn from its own key, ``fold_in(base, leaf_id)``.  A
+layer's leaves, with their shapes, scales and ids, are its
+architecture's (`archs`: ``layer_leaves``), and so is how layers group
+into stacked trees (``groups``).  The two vocabulary-sized matrices are
+drawn in row blocks of `VOCAB_BLOCK`, each from its own key: the
+reference can then make the embedding rows and the LM head one block at
+a time, in memory it can afford.
 
 Scales follow the program's own initialisation: N(0, 1) embeddings,
 fan-in scaled projections, zero RMSNorm offsets (the norm multiplies by
@@ -19,10 +22,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-VOCAB_BLOCK = 32768
+import archs
 
-# leaf ids of a layer's matrices: key = fold_in(fold_in(base, 1000 + l), id)
-_LAYER_LEAVES = ("wq", "wk", "wv", "wo", "w_up", "w_down", "w_gate")
+VOCAB_BLOCK = 32768
 
 
 def base_key(seed: int):
@@ -36,30 +38,17 @@ def _normal(key, shape, std, dtype):
     return (jax.random.normal(key, shape, dtype) * jnp.asarray(std, dtype)).astype(dtype)
 
 
-def layer_shapes(cfg: dict) -> dict:
-    D, H, Hkv, Dh, F = (cfg["d_model"], cfg["n_heads"], cfg["n_kv_heads"],
-                        cfg["head_dim"], cfg["d_ff"])
-    shapes = {
-        "wq": ((D, H, Dh), D ** -0.5),
-        "wk": ((D, Hkv, Dh), D ** -0.5),
-        "wv": ((D, Hkv, Dh), D ** -0.5),
-        "wo": ((H, Dh, D), (H * Dh) ** -0.5),
-        "w_up": ((D, F), D ** -0.5),
-        "w_down": ((F, D), F ** -0.5),
-    }
-    if cfg["mlp_type"] == "swiglu":
-        shapes["w_gate"] = ((D, F), D ** -0.5)
-    return shapes
-
-
 def layer(cfg: dict, key, l: int, dtype) -> dict:
-    """Layer `l`'s weights: {attn_norm, mlp_norm, wq, wk, wv, wo, mlp{...}}."""
+    """Layer `l`'s weights, as its architecture lays them out
+    (``layer_leaves``): leaf `i` from ``fold_in(fold_in(key, 1000 + l), i)``."""
     lk = jax.random.fold_in(key, 1000 + l)
-    D = cfg["d_model"]
-    out = {"attn_norm": jnp.zeros((D,), dtype), "mlp_norm": jnp.zeros((D,), dtype), "mlp": {}}
-    for name, (shape, std) in layer_shapes(cfg).items():
-        w = _normal(jax.random.fold_in(lk, _LAYER_LEAVES.index(name)), shape, std, dtype)
-        (out["mlp"] if name.startswith("w_") else out)[name] = w
+    out: dict = {}
+    for path, shape, std, leaf_id in archs.of(cfg).layer_leaves(cfg, l):
+        node = out
+        for p in path[:-1]:
+            node = node.setdefault(p, {})
+        node[path[-1]] = (jnp.zeros(shape, dtype) if std == 0 else
+                          _normal(jax.random.fold_in(lk, leaf_id), shape, std, dtype))
     return out
 
 
@@ -83,17 +72,20 @@ def n_vocab_blocks(cfg: dict) -> int:
 
 def make_params(cfg: dict, seed: int, dtype=None):
     """The whole parameter tree on the default device, in one jitted call.
-    Layers are stacked on a leading axis, as the program stores them."""
+    The layers of each of the architecture's groups are stacked on a
+    leading axis under the group's key, as the program stores them."""
     dtype = jnp.dtype(dtype or cfg["dtype"])
 
     def build(key):
-        layers = [layer(cfg, key, l, dtype) for l in range(cfg["n_layers"])]
         nb = n_vocab_blocks(cfg)
-        return {
+        tree = {
             "embed": jnp.concatenate([embed_block(cfg, key, b, dtype) for b in range(nb)]),
-            "layers": jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *layers),
             "final_norm": jnp.zeros((cfg["d_model"],), dtype),
             "lm_head": jnp.concatenate([head_block(cfg, key, b, dtype) for b in range(nb)], axis=1),
         }
+        for name, ls in archs.of(cfg).groups(cfg):
+            layers = [layer(cfg, key, l, dtype) for l in ls]
+            tree[name] = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *layers)
+        return tree
 
     return jax.jit(build)(base_key(seed))
