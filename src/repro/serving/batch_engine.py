@@ -62,6 +62,7 @@ from repro.serving.kv_pool import (
     KVExport,
     PagedKVPool,
     PoolExhausted,
+    arena_set,
     page_views,
     pool_for,
 )
@@ -224,7 +225,8 @@ def _decode_step(
     The paged route writes each layer's fresh K/V into the arena
     *before* attention, so the kernel reads the new token (tagged with
     logical position len) through the same page view as every cached
-    token — the gather path's explicit concat disappears.
+    token — the gather path's explicit concat disappears.  The write is
+    `kv_pool.arena_set`'s, so no layer changes the arenas' layout.
 
     Jitted by `_decode_step_jit` with the arenas donated where the
     backend implements donation (TPU/GPU), so the update is in-place;
@@ -263,12 +265,8 @@ def _decode_step(
         q = jnp.einsum("nd,dhe->nhe", h, lp["wq"])
         k_new = jnp.einsum("nd,dhe->nhe", h, lp["wk"])  # pre-RoPE
         v_new = jnp.einsum("nd,dhe->nhe", h, lp["wv"])
-        arena_k = arena_k.at[new_pages, layer, :, new_slots].set(
-            k_new.astype(arena_k.dtype)
-        )
-        arena_v = arena_v.at[new_pages, layer, :, new_slots].set(
-            v_new.astype(arena_v.dtype)
-        )
+        arena_k = arena_set(arena_k, new_pages, new_slots, k_new, layer)
+        arena_v = arena_set(arena_v, new_pages, new_slots, v_new, layer)
 
         q = L.apply_rope(q[:, None], pos_new[:, None], cfg.rope_theta)[:, 0]
         if paged:

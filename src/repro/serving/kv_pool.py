@@ -35,6 +35,15 @@ the update is in place; CPU lacks donation, and there each write copies
 the arena.  The decode hot loop threads the arenas through the jitted
 decode step (`serving.batch_engine`) and installs the returned buffers,
 so the new tokens' KV lands in-step.
+
+Layout contract: every indexed write or read of an arena goes through
+`arena_set` / `arena_get`, whose indices keep each scatter's or
+gather's window in the arena's operand order.  Indexing pages and
+slots (dims 0 and 3) around a head slice, ``arena.at[pages, l, :,
+slots]``, leaves a (Hkv, Dh) window with the slot dim between its
+dims: XLA:TPU then transposes the whole arena to another layout and
+back around the op, so its cost grows with the pool instead of with
+the rows written.
 """
 from __future__ import annotations
 
@@ -75,16 +84,46 @@ def _donates(arr) -> bool:
     return any(d.platform in ("tpu", "gpu") for d in arr.devices())
 
 
+def arena_set(arena, pages, slots, rows, layers):
+    """`arena` with token rows written at (page, slot) of layer planes
+    `layers`: one layer index, rows (t, Hkv, Dh); or a `range` of
+    planes, rows (t, len(layers), Hkv, Dh).
+
+    The index names the layer and head of each row too, so the
+    scatter's window is one head's Dh, the arena's minor dim: one native
+    scatter, no layout change.  On an arena whose head axis is sharded
+    (`mesh.tp > 1`) each device writes its own heads' windows, with no
+    collective.
+    """
+    if not isinstance(layers, range):
+        layers, rows = range(layers, layers + 1), rows[:, None]
+    lay = jnp.arange(layers.start, layers.stop)
+    heads = jnp.arange(arena.shape[2])
+    return arena.at[pages[:, None, None], lay[None, :, None],
+                    heads[None, None], slots[:, None, None]].set(
+                        rows.astype(arena.dtype))
+
+
+def arena_get(arena, pages, slots):
+    """Token rows (t, L, Hkv, Dh) at (page, slot) of `arena`, indexed
+    per layer and head like `arena_set`: the gather's window is one
+    head's Dh and the arena keeps its layout."""
+    lay = jnp.arange(arena.shape[1])
+    heads = jnp.arange(arena.shape[2])
+    return arena[pages[:, None, None], lay[None, :, None],
+                 heads[None, None], slots[:, None, None]]
+
+
 def _pool_scatter(arena_k, arena_v, pages, slots, rows, k, v, planes):
     """Rows `rows` of the sources `k`/`v` into (page, slot) of both
     arenas, in the layer planes `planes`: "all", "deep" (1..L-1) or one
     layer index."""
     with jax.named_scope("pool_scatter"):
-        sel = {"all": slice(None), "deep": slice(1, None)}.get(planes, planes)
-        return (arena_k.at[pages, sel, :, slots].set(
-                    k[rows].astype(arena_k.dtype)),
-                arena_v.at[pages, sel, :, slots].set(
-                    v[rows].astype(arena_v.dtype)))
+        n_layers = arena_k.shape[1]
+        layers = {"all": range(n_layers),
+                  "deep": range(1, n_layers)}.get(planes, planes)
+        return (arena_set(arena_k, pages, slots, k[rows], layers),
+                arena_set(arena_v, pages, slots, v[rows], layers))
 
 
 @functools.lru_cache(maxsize=None)
@@ -99,7 +138,7 @@ def _pool_scatter_jit(donate: bool):
 @jax.named_scope("pool_gather")
 def _pool_gather(arena_k, arena_v, pages, slots):
     """Token rows (t, L, Hkv, Dh) at (page, slot) of both arenas."""
-    return arena_k[pages, :, :, slots], arena_v[pages, :, :, slots]
+    return arena_get(arena_k, pages, slots), arena_get(arena_v, pages, slots)
 
 
 @dataclass(frozen=True)
@@ -679,9 +718,8 @@ class PagedKVPool:
         n = self.seq_lens[rid]
         sl = self.slot_tables[rid][:n]
         pages, slots = sl // self.page_size, sl % self.page_size
-        k = np.asarray(self.arena_k[pages, :, :, slots])
-        v = np.asarray(self.arena_v[pages, :, :, slots])
-        return k, v
+        k, v = _pool_gather(self.arena_k, self.arena_v, pages, slots)
+        return np.asarray(k), np.asarray(v)
 
     def batch_tables(self, rids: Sequence[int], pad_pages_to: int = 4
                      ) -> Tuple[np.ndarray, np.ndarray]:

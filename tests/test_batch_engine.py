@@ -71,6 +71,43 @@ def test_pool_write_gather_roundtrip(rng):
     assert len(pool.page_tables[7]) > pages0
 
 
+@pytest.mark.parametrize("src_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("planes", [0, "deep", "all"])
+def test_arena_rows_match_slot_indexing(rng, planes, src_dtype):
+    """The pool's scatter and gather (`kv_pool.arena_set`/`arena_get`)
+    write and read exactly what plain ``arena.at[pages, layers, :,
+    slots]`` indexing does, bit for bit — float32 rows (the prefill's
+    planes) and bfloat16 rows cast into the float32 arena (the decode
+    step's fresh K/V), pad rows landing twice on the scratch slot (0, 0)
+    included."""
+    import jax.numpy as jnp
+
+    from repro.serving import kv_pool as KV
+
+    P, L, H, page, D, t = 6, 3, 2, 4, 8, 9
+    sel = {0: 0, "deep": slice(1, None), "all": slice(None)}[planes]
+    arena_k = jnp.asarray(rng.normal(size=(P, L, H, page, D)), jnp.float32)
+    arena_v = -arena_k
+    slot_ids = rng.choice(np.arange(page, P * page), t - 2, replace=False)
+    pages = np.concatenate([slot_ids // page, [0, 0]]).astype(np.int32)
+    slots = np.concatenate([slot_ids % page, [0, 0]]).astype(np.int32)
+    rows = np.concatenate([rng.permutation(t - 2), [0, 0]]).astype(np.int32)
+    n_planes = np.arange(L)[sel].size
+    src = (t, H, D) if planes == 0 else (t, n_planes, H, D)
+    k = jnp.asarray(rng.normal(size=src), src_dtype)
+    v = k * 3
+
+    got_k, got_v = KV._pool_scatter(arena_k, arena_v, pages, slots, rows, k, v,
+                                    planes)
+    want_k = arena_k.at[pages, sel, :, slots].set(k[rows].astype(jnp.float32))
+    want_v = arena_v.at[pages, sel, :, slots].set(v[rows].astype(jnp.float32))
+    np.testing.assert_array_equal(got_k, want_k)
+    np.testing.assert_array_equal(got_v, want_v)
+    read_k, read_v = KV._pool_gather(got_k, got_v, pages, slots)
+    np.testing.assert_array_equal(read_k, got_k[pages, :, :, slots])
+    np.testing.assert_array_equal(read_v, got_v[pages, :, :, slots])
+
+
 def test_plan_spans_partition(tiny_system):
     from repro.data import synth as SY
     system, pool_rv, prof, _ = tiny_system

@@ -36,7 +36,8 @@ BF16, F32 = jnp.bfloat16, jnp.float32
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
+    """The four chips of a described v5e 2x2 host."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -50,9 +51,14 @@ def one_chip():
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", enabled)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 def _spec(shape, dtype, sharding):
@@ -139,3 +145,83 @@ def test_decode_step_compiles(one_chip, monkeypatch):
     # weights + two arenas (donated: updated in place) fit one v5e's HBM
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < 16e9, used
+
+
+@pytest.mark.parametrize("program", [0, "deep", "all", "gather", "decode_step"])
+def test_arena_programs_keep_the_arena_layout(one_chip, monkeypatch, program):
+    """Every program that indexes the paged KV arena writes and reads it
+    in its own layout: the compiled program holds no copy of an
+    arena-shaped array, which would be XLA transposing the whole pool
+    to make an index window contiguous (`kv_pool.arena_set`), and no
+    `while` loop carrying the arena, which is how a scatter with a
+    window the TPU cannot write natively runs (row by row).  The pool
+    scatter is taken at a layer plane, the deep planes and all planes;
+    64 pages are enough for the compiler to pick another layout."""
+    import re
+
+    from repro.models import transformer as T
+    from repro.serving import batch_engine as BE
+    from repro.serving import kv_pool as KV
+
+    monkeypatch.setattr(BE, "default_interpret", lambda: False)
+    cfg = dataclasses.replace(QWEN3_8B, n_layers=8, dtype="bfloat16", remat=False,
+                              attn_backend="pallas")
+    L, T_ROWS = cfg.n_layers, 512
+    shape = (64, L, HKV, PAGE, DH)
+    arena = _spec(shape, F32, one_chip)
+    i32 = lambda *s: _spec(s, jnp.int32, one_chip)  # noqa: E731
+    if program == "gather":
+        lowered = KV._pool_gather.lower(arena, arena, i32(T_ROWS), i32(T_ROWS))
+    elif program == "decode_step":
+        params = jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0), cfg))
+        params = jax.tree_util.tree_map(
+            lambda a: _spec(a.shape, a.dtype, one_chip), params)
+        N, S = 8, 512
+        pmax = S // PAGE + 4
+        lowered = BE._decode_step_jit(True).lower(
+            params, i32(N), i32(N, S), i32(N), i32(N), i32(N), i32(N, pmax),
+            i32(N, pmax, PAGE), arena, arena, cfg)
+    else:
+        planes = {0: (), "deep": (L - 1,), "all": (L,)}[program]
+        rows = _spec((T_ROWS, *planes, HKV, DH), F32, one_chip)
+        lowered = KV._pool_scatter_jit(True).lower(
+            arena, arena, i32(T_ROWS), i32(T_ROWS), i32(T_ROWS), rows, rows, program)
+    text = lowered.compile().as_text()
+    dims = ",".join(map(str, shape))
+    copies = re.findall(rf"= f32\[{dims}\]\{{[^}}]*\}} copy\(", text)
+    assert not copies, f"{len(copies)} whole-arena copies"
+    loops = [ln for ln in text.splitlines()
+             if " while(" in ln and f"f32[{dims}]" in ln]
+    assert not loops, f"{len(loops)} while loops carry the arena"
+
+
+@pytest.mark.parametrize("planes", [0, "deep", "all"])
+def test_head_sharded_arena_scatter_is_local(v5e_2x2, planes):
+    """Under ``mesh.tp > 1`` the arena is split over its kv heads (dim
+    2) and the pool scatter's rows arrive whole: each chip writes its
+    own heads' windows (`kv_pool.arena_set`), so the compiled program
+    holds no collective, no copy of its arena shard and no `while`."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.serving import kv_pool as KV
+
+    mesh = Mesh(np.array(v5e_2x2).reshape(1, 4), ("data", "model"))
+    whole = NamedSharding(mesh, P())
+    L, T_ROWS = 8, 512
+    arena = _spec((64, L, HKV, PAGE, DH), F32,
+                  NamedSharding(mesh, P(None, None, "model", None, None)))
+    idx = _spec((T_ROWS,), jnp.int32, whole)
+    n_planes = {0: (), "deep": (L - 1,), "all": (L,)}[planes]
+    rows = _spec((T_ROWS, *n_planes, HKV, DH), F32, whole)
+    text = KV._pool_scatter_jit(True).lower(
+        arena, arena, idx, idx, idx, rows, rows, planes).compile().as_text()
+    collectives = re.findall(
+        r"(all-gather|all-reduce|all-to-all|collective-permute|reduce-scatter)", text)
+    assert not collectives, collectives
+    shard = ",".join(map(str, (64, L, HKV // 4, PAGE, DH)))
+    assert not re.findall(rf"= f32\[{shard}\]\{{[^}}]*\}} copy\(", text)
+    assert " while(" not in text
